@@ -33,6 +33,8 @@ def main() -> None:
                     help="skip wall-clock host benchmarks (CI)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
 

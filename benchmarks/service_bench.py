@@ -225,11 +225,10 @@ def chaos_probe() -> dict:
     from repro.core import (AnalysisService, BreakerConfig, FaultPlan,
                             FaultSpec)
     from repro.core.engine import AnalysisRequest
-    from repro.core.sim import has_jax
     from repro.service import (PredictionService, ServiceConfig,
                                ServiceRequest, replay)
 
-    primary = "jit" if has_jax() else "numpy"
+    primary = "jit"
     plan = FaultPlan(specs=(
         FaultSpec(point="engine.dispatch", mode="fail",
                   match={"backend": primary}),))
@@ -500,6 +499,8 @@ def main() -> None:
                          "result drift")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     report = run_bench(fast=args.fast)
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(report, f, indent=2)

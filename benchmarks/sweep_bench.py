@@ -70,7 +70,7 @@ def _time(fn, repeats: int = 1) -> float:
 def bench_batches(batches: list[int], repeats: int = 2) -> list[dict]:
     """Driver throughput at each batch size; same programs, bit-equal
     results across backends (asserted)."""
-    from repro.core.sim import has_jax, simulate, simulate_many
+    from repro.core.sim import simulate, simulate_many
 
     base = _build_programs()
     rows = []
@@ -94,26 +94,25 @@ def bench_batches(batches: list[int], repeats: int = 2) -> list[dict]:
             "points_per_s": round(B / t_np, 2),
         }
 
-        if has_jax():
-            res_np = simulate_many(progs, backend="numpy")
-            t_cold = _time(lambda: simulate_many(progs, backend="jit"))
-            t_jit = _time(lambda: simulate_many(progs, backend="jit"),
-                          repeats)
-            res_jit = simulate_many(progs, backend="jit")
-            drift = max(abs(a.cycles_per_iteration -
-                            b.cycles_per_iteration)
-                        for a, b in zip(res_np, res_jit))
-            assert drift < 1e-9, f"backend drift {drift}"
-            row["backends"]["jit"] = {
-                "cold_seconds": round(t_cold, 4),
-                "seconds": round(t_jit, 4),
-                "points_per_s": round(B / t_jit, 2),
-                "max_drift_vs_numpy": drift,
-            }
-            row["speedup_jit_vs_numpy"] = round(t_np / t_jit, 2)
-            row["speedup_jit_vs_pointwise"] = round(
-                (B / t_jit) / row["backends"]["pointwise"]
-                ["points_per_s"], 2)
+        res_np = simulate_many(progs, backend="numpy")
+        t_cold = _time(lambda: simulate_many(progs, backend="jit"))
+        t_jit = _time(lambda: simulate_many(progs, backend="jit"),
+                      repeats)
+        res_jit = simulate_many(progs, backend="jit")
+        drift = max(abs(a.cycles_per_iteration -
+                        b.cycles_per_iteration)
+                    for a, b in zip(res_np, res_jit))
+        assert drift < 1e-9, f"backend drift {drift}"
+        row["backends"]["jit"] = {
+            "cold_seconds": round(t_cold, 4),
+            "seconds": round(t_jit, 4),
+            "points_per_s": round(B / t_jit, 2),
+            "max_drift_vs_numpy": drift,
+        }
+        row["speedup_jit_vs_numpy"] = round(t_np / t_jit, 2)
+        row["speedup_jit_vs_pointwise"] = round(
+            (B / t_jit) / row["backends"]["pointwise"]
+            ["points_per_s"], 2)
         rows.append(row)
     return rows
 
@@ -124,16 +123,13 @@ def bench_sweep(cells_target: int = 1024) -> dict:
     from repro.core import AnalysisService
     from repro.core import paper_kernels as pk
 
-    from repro.core.sim import has_jax
-
     kernels = {"triad_skl": pk.TRIAD_SKL_O3, "triad_zen": pk.TRIAD_ZEN_O3,
                "pi_o1": pk.PI_O1, "pi_o2": pk.PI_O2,
                "pi_skl_o3": pk.PI_SKL_O3, "pi_zen_o3": pk.PI_ZEN_O3}
     # force the compiled driver: "auto" would pick numpy here (each
     # machine group holds only len(kernels) unique programs, below
-    # AUTO_JIT_MIN_BATCH), and the recorded trajectory must say which
-    # driver it measured
-    backend = "jit" if has_jax() else "numpy"
+    # AUTO_JIT_MIN_BATCH)
+    backend = "jit"
     svc = AnalysisService(sim_backend=backend)
     reps = max(1, cells_target // (len(kernels) * 2 * 2))
     # cold: the first grid pays parsing, analytic passes, program
@@ -360,7 +356,7 @@ def bench_compaction(cells_target: int = 10000,
 
 
 def run_bench(fast: bool = False) -> dict:
-    from repro.core.sim import AUTO_JIT_MIN_BATCH, JIT_SHARD, has_jax
+    from repro.core.sim import AUTO_JIT_MIN_BATCH, JIT_SHARD
 
     batches = [1, 64, 256] if fast else [1, 64, 1024]
     report = {
@@ -369,15 +365,14 @@ def run_bench(fast: bool = False) -> dict:
                  "platform": platform.platform(),
                  "python": platform.python_version()},
         "config": {"fast": fast, "jit_shard": JIT_SHARD,
-                   "auto_jit_min_batch": AUTO_JIT_MIN_BATCH,
-                   "jax_available": has_jax()},
+                   "auto_jit_min_batch": AUTO_JIT_MIN_BATCH},
         "batches": bench_batches(batches, repeats=1 if fast else 2),
         "sweep": bench_sweep(256 if fast else 1024),
         "resume": bench_resume(),
         "compaction": bench_compaction(),
     }
     gate_rows = [r for r in report["batches"]
-                 if r["batch"] >= 64 and "jit" in r["backends"]]
+                 if r["batch"] >= 64]
     # the jit-vs-numpy speedup scales with how many cores the shard
     # pool gets; 10x was measured on a 16-core host.  Scale the target
     # to this container so the gate carries signal instead of being a
@@ -456,6 +451,8 @@ def main() -> None:
                     help="exit 1 unless jit >= numpy at batch >= 64")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     report = run_bench(fast=args.fast)
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(report, f, indent=2)

@@ -56,20 +56,18 @@ def main():
         print(f"{name:16s} {sim.cycles_per_iteration:6.2f} cy/it  "
               f"(converged={sim.converged}, {sim.bottleneck})")
 
-    from repro.core.sim import has_jax
-    if has_jax():
-        print()
-        print("Compiled backend (jax.jit, float64): same numbers to 1e-9")
-        for name, sim in zip(CASES,
-                             simulate_many(programs, backend="jit")):
-            print(f"{name:16s} {sim.cycles_per_iteration:6.2f} cy/it")
-        # a bulk sweep dispatches one compiled call per machine model:
-        grid = svc.sweep({n: src for n, (_, src, _) in CASES.items()},
-                         archs=("skl", "zen"), mode="simulate",
-                         backend="jit")
-        print(f"sweep: {len(grid)} cells, "
-              f"{svc.stats.sim_group_dispatches} compiled dispatches "
-              f"(see docs/performance.md and BENCH_sweep.json)")
+    print()
+    print("Compiled backend (jax.jit, float64): same numbers to 1e-9")
+    for name, sim in zip(CASES,
+                         simulate_many(programs, backend="jit")):
+        print(f"{name:16s} {sim.cycles_per_iteration:6.2f} cy/it")
+    # a bulk sweep dispatches one compiled call per machine model:
+    grid = svc.sweep({n: src for n, (_, src, _) in CASES.items()},
+                     archs=("skl", "zen"), mode="simulate",
+                     backend="jit")
+    print(f"sweep: {len(grid)} cells, "
+          f"{svc.stats.sim_group_dispatches} compiled dispatches "
+          f"(see docs/performance.md and BENCH_sweep.json)")
 
 
 if __name__ == "__main__":

@@ -74,7 +74,7 @@ class AnalysisResult:
     # --- degradation provenance (docs/robustness.md) --------------------
     degraded: bool = False                # a cheaper backend answered after
     #                                       the requested one failed
-    backend_used: str = ""                # fallback rung ("" = as requested)
+    backend_used: str = ""                # rung that answered ("" = none)
     fault_trace_id: int = 0               # FaultInjector event id (0 = none)
     routed_from: str = ""                 # rung the HealthRouter skipped
     #                                       pre-dispatch ("" = not routed)

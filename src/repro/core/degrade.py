@@ -44,8 +44,10 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Sequence
 
+from .faults import InjectedFault, ResultValidationError
+
 __all__ = [
-    "LADDER", "ladder_from", "BreakerConfig", "CircuitBreaker",
+    "LADDER", "ladder_from", "contained_faults", "BreakerConfig", "CircuitBreaker",
     "BreakerBoard", "validate_sims", "RouterConfig", "RoutePlan",
     "HealthRouter",
 ]
@@ -67,6 +69,20 @@ def ladder_from(backend: str) -> tuple[str, ...]:
         raise ValueError(f"unknown sim backend {backend!r}; "
                          f"known: {', '.join(LADDER)} or 'tick'") from None
     return LADDER[i:]
+
+
+def contained_faults() -> tuple[type[Exception], ...]:
+    """The failures a rung contains by demoting: injected faults,
+    validator rejections and device runtime errors.
+
+    Anything else — ``ImportError``, ``AttributeError``, ``TypeError``,
+    ``NotImplementedError``, ``ValueError`` — is a bug or a bad request
+    and propagates; demoting it would turn a broken device path into
+    quiet answers from a cheaper rung.  :class:`~.faults.FaultAbort` is
+    an ``InjectedFault`` too, so handlers re-raise it first.  Called in
+    the ``except`` clause, so jax is imported only once a rung fails."""
+    from jax.errors import JaxRuntimeError
+    return (InjectedFault, ResultValidationError, JaxRuntimeError)
 
 
 @dataclass(frozen=True)
@@ -375,20 +391,21 @@ def validate_sims(sims: Sequence, progs: Sequence,
     problems: list[str] = []
     for sim, prog in zip(sims, progs):
         cpi = sim.cycles_per_iteration
+        label = prog.digest[:12]
         if not math.isfinite(cpi):
-            problems.append(f"{prog.kernel_id}: non-finite cycles ({cpi})")
+            problems.append(f"{label}: non-finite cycles ({cpi})")
             continue
         if cpi < 0:
-            problems.append(f"{prog.kernel_id}: negative cycles ({cpi})")
+            problems.append(f"{label}: negative cycles ({cpi})")
             continue
         bound = prog.port_bound_cycles
         if bound > 0:
             if cpi > bound * divergence_factor:
                 problems.append(
-                    f"{prog.kernel_id}: {cpi:.3f} cy/it diverges above "
+                    f"{label}: {cpi:.3f} cy/it diverges above "
                     f"{divergence_factor:.0f}x the {bound:.3f} port bound")
             elif cpi * divergence_factor < bound:
                 problems.append(
-                    f"{prog.kernel_id}: {cpi:.3f} cy/it diverges below "
+                    f"{label}: {cpi:.3f} cy/it diverges below "
                     f"1/{divergence_factor:.0f}x the {bound:.3f} port bound")
     return problems

@@ -48,7 +48,7 @@ from .analysis import AnalysisResult, analyze
 from .arch.registry import ArchRegistry, UnknownArchError, default_registry
 from .database import InstructionDB
 from .degrade import (BreakerBoard, BreakerConfig, HealthRouter,
-                      ladder_from, validate_sims)
+                      contained_faults, ladder_from, validate_sims)
 from .faults import (FaultAbort, FaultInjector, FaultPlan, InjectedFault,
                      ResultValidationError)
 from .isa import Instruction
@@ -216,9 +216,8 @@ class AnalysisService:
         #: behavior, bit-identical: the ladder still demotes on
         #: failure but never skips a rung pre-dispatch)
         self.router = router
-        # provenance for sims produced below the requested rung or via
-        # a routed/probe dispatch: sim_key -> (backend_used, degraded,
-        # fault event id, routed_from, probe)
+        # provenance of every simulated cell: sim_key -> (rung that
+        # answered, degraded, fault event id, routed_from, probe)
         self._sim_provenance: dict[tuple, tuple[str, bool, int, str,
                                                 bool]] = {}
         # registry epoch at the last cache fill: a replacing
@@ -575,10 +574,11 @@ class AnalysisService:
         from / stored in the shared cache) refined by the cycle-level
         simulator.
 
-        The tick-loop driver is its own single-rung ladder: a failed
-        compile or simulation (injected or real) is contained and the
-        cell degrades to the analytic floor with ``degraded``
-        provenance rather than failing the request — the analytic and
+        The tick-loop driver is its own single-rung ladder: a fault in
+        compile or simulation (:func:`~.degrade.contained_faults`) is
+        contained and the cell degrades to the analytic floor with
+        ``degraded`` provenance rather than failing the request; any
+        other exception is a bug and propagates — the analytic and
         simulated predictors are redundant estimates of the same
         quantity (docs/robustness.md)."""
         import dataclasses
@@ -642,14 +642,11 @@ class AnalysisService:
                 breaker.record_success()
                 with self._lock:
                     self._sim_cache[sim_key] = sim
-                    if probe:
-                        self._sim_provenance[sim_key] = (
-                            "tick", False, 0, "", True)
+                    self._sim_provenance[sim_key] = (
+                        "tick", False, 0, "", probe)
             except FaultAbort:
                 raise               # simulated process kill: never contained
-            except ValueError:
-                raise               # bad request, not a backend fault
-            except Exception as exc:
+            except contained_faults() as exc:
                 breaker.record_failure()
                 event_id = getattr(exc, "event_id", 0)
                 with self._lock:
@@ -658,14 +655,24 @@ class AnalysisService:
         res = self._combine_sim(analytic, sim)
         with self._lock:
             prov = self._sim_provenance.get(sim_key)
-        if prov is not None:
-            if prov[1]:
-                res = dataclasses.replace(
-                    res, degraded=True, backend_used=prov[0],
-                    fault_trace_id=prov[2])
-            if prov[3] or prov[4]:
-                res = dataclasses.replace(
-                    res, routed_from=prov[3], probe=prov[4])
+        return res if prov is None else self._with_provenance(res, prov)
+
+    @staticmethod
+    def _with_provenance(res: AnalysisResult, prov: tuple
+                         ) -> AnalysisResult:
+        """Stamp a combined ``mode="simulate"`` result with the rung
+        that answered it and, when it was degraded, routed or a probe,
+        with that provenance too."""
+        import dataclasses
+
+        backend_used, degraded, event_id, routed_from, probe = prov
+        res = dataclasses.replace(res, backend_used=backend_used)
+        if degraded:
+            res = dataclasses.replace(res, degraded=True,
+                                      fault_trace_id=event_id)
+        if routed_from or probe:
+            res = dataclasses.replace(res, routed_from=routed_from,
+                                      probe=probe)
         return res
 
     @staticmethod
@@ -702,7 +709,8 @@ class AnalysisService:
         Walks the sim rungs from ``start`` (``("tick",)`` for the
         small-batch reference loop), skipping rungs whose circuit
         breaker is open, validating every rung's output, and demoting
-        on any contained failure.  When a :class:`HealthRouter` is
+        on any contained fault (:func:`~.degrade.contained_faults`).
+        When a :class:`HealthRouter` is
         installed it is consulted *before* the walk: rungs with an
         open breaker are dropped without paying a dispatch and at
         most one scheduled probe per cooldown window reaches a rung
@@ -710,8 +718,8 @@ class AnalysisService:
         degraded, dispatches, fault event id, routed_from, probe)`` —
         ``sims is None`` means every rung failed and the group takes
         the analytic floor.  :class:`FaultAbort` (a simulated process
-        kill) and ``ValueError`` (a deterministic bad request) are
-        never contained."""
+        kill) and every exception that is not a contained fault (a bug
+        or a bad request) propagate."""
         import dataclasses
 
         from .sim import simulate, simulate_many
@@ -775,9 +783,7 @@ class AnalysisService:
                         event_id, routed_from, probe)
             except FaultAbort:
                 raise
-            except ValueError:
-                raise
-            except Exception as exc:
+            except contained_faults() as exc:
                 breaker.record_failure()
                 event_id = getattr(exc, "event_id", event_id)
                 demoted = True
@@ -1092,11 +1098,10 @@ class AnalysisService:
                             self._sim_cache.setdefault(sk, sim)
                         if degraded:
                             self.stats.degraded_results += len(sks)
-                        if degraded or routed_from or probe:
-                            for sk in sks:
-                                self._sim_provenance[sk] = (
-                                    backend_used, degraded, event_id,
-                                    routed_from, probe)
+                        for sk in sks:
+                            self._sim_provenance[sk] = (
+                                backend_used, degraded, event_id,
+                                routed_from, probe)
             # combine analytic base + simulation per cell
             import dataclasses
             for k, req in sim_cells.items():
@@ -1125,13 +1130,7 @@ class AnalysisService:
                     res = self._apply_ecm(self._combine_sim(analytic, sim),
                                           req)
                     if prov is not None:
-                        if prov[1]:
-                            res = dataclasses.replace(
-                                res, degraded=True, backend_used=prov[0],
-                                fault_trace_id=prov[2])
-                        if prov[3] or prov[4]:
-                            res = dataclasses.replace(
-                                res, routed_from=prov[3], probe=prov[4])
+                        res = self._with_provenance(res, prov)
                 with self._lock:
                     self._results.setdefault(k, res)
 

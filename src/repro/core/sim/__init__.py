@@ -9,8 +9,7 @@ the three backends compose.
 """
 from __future__ import annotations
 
-from .batch import (AUTO_JIT_MIN_BATCH, JIT_SHARD, has_jax,
-                    simulate_many)
+from .batch import AUTO_JIT_MIN_BATCH, JIT_SHARD, simulate_many
 from .dag import DagNode, DagSchedule, schedule_dag
 from .pipeline import (BOTTLENECKS, DEFAULT_PARAMS, FE_MODE_NAMES,
                        FrontendSchedule, SimProgram, SimResult, SimUop,
@@ -21,6 +20,6 @@ __all__ = [
     "AUTO_JIT_MIN_BATCH", "BOTTLENECKS", "DEFAULT_PARAMS",
     "DagNode", "DagSchedule", "FE_MODE_NAMES", "FrontendSchedule",
     "JIT_SHARD", "SimProgram", "SimResult", "SimUop", "compile_program",
-    "frontend_schedule", "has_jax", "schedule_dag", "simulate",
+    "frontend_schedule", "schedule_dag", "simulate",
     "simulate_kernel", "simulate_many",
 ]

@@ -17,7 +17,7 @@ Two interchangeable backends run that function (``backend=``):
   with ``[batch]``-vectorized numpy ops per slot.
 * ``"jit"`` — the same recurrence compiled with ``jax.jit``:
   ``lax.scan`` over iterations and over uop slots, operating on
-  ``[shard, ...]`` arrays in float64 (``enable_x64``) so the two
+  ``[shard, ...]`` arrays in float64 (``jax.enable_x64``) so the two
   backends agree to 1e-9 (``tests/test_sweep_engine.py`` locks this).
   Batches are cut into fixed-size, cache-resident shards (padded with
   empty lanes), so one compiled executable per (shape bucket, machine)
@@ -33,8 +33,9 @@ Two interchangeable backends run that function (``backend=``):
   running scalars plus an incrementally-maintained per-edge source
   vector (no gather/scatter in the inner step at all).
 * ``"pallas"`` — the jit driver with the port-arbitration inner step
-  swapped for a Pallas kernel (``sim/pallas_step.py``); built for TPU
-  fleets, interpreted (slow, exact) elsewhere.
+  swapped for a Pallas kernel (``sim/pallas_step.py``), run in
+  interpret mode (slow, exact).  The TPU's kernel compiler takes no
+  float64, so on a TPU this backend is refused up front.
 
 The reformulation replaces the per-cycle oldest-ready arbitration with
 its program-order dataflow equivalent: each uop books the eligible port
@@ -94,17 +95,6 @@ from .pipeline import (DEFAULT_PARAMS, SimProgram, SimResult, _classify,
 #: compiled driver (below it, numpy's per-slot loop is cheaper than a
 #: compile-cache lookup + device transfer)
 AUTO_JIT_MIN_BATCH = 16
-
-
-def has_jax() -> bool:
-    """True when the compiled (``"jit"`` / ``"pallas"``) backends can
-    run in this process."""
-    try:
-        import jax  # noqa: F401
-        import jax.experimental  # noqa: F401
-    except Exception:      # pragma: no cover - env without jax
-        return False
-    return True
 
 
 @dataclass
@@ -695,9 +685,9 @@ def _run_jax(programs: list[SimProgram], ports: tuple[str, ...],
              flavor: str) -> np.ndarray:
     """Shard + run the compiled recurrence; agrees with
     :func:`_run_numpy` to 1e-9 because it executes the identical
-    arithmetic in float64 (``jax.experimental.enable_x64``)."""
+    arithmetic in float64 (``jax.enable_x64``)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     B = len(programs)
     model = programs[0].model
@@ -709,7 +699,7 @@ def _run_jax(programs: list[SimProgram], ports: tuple[str, ...],
         shards.append(_pack_lean(chunk, ports, params, n_iterations))
 
     def run_shard(pk: dict) -> np.ndarray:
-        with enable_x64():
+        with jax.enable_x64(True):
             fn = _compiled_run(pk["U"], pk["E"], len(ports),
                                n_iterations, params, flavor)
             args = [jnp.asarray(pk[k]) for k in _LEAN_ARGS]
@@ -762,14 +752,16 @@ def _steady_state(iter_end: np.ndarray, warmup: int, max_period: int
 
 def _resolve_backend(backend: str, batch: int) -> str:
     if backend == "auto":
-        if batch >= AUTO_JIT_MIN_BATCH and has_jax():
-            return "jit"
-        return "numpy"
-    if backend in ("numpy", "jit", "pallas"):
-        if backend != "numpy" and not has_jax():
-            raise RuntimeError(
-                f"backend={backend!r} requires jax, which failed to "
-                "import; install jax or use backend='numpy'")
+        return "jit" if batch >= AUTO_JIT_MIN_BATCH else "numpy"
+    if backend == "pallas":
+        import jax
+        if jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "backend='pallas' cannot run on a TPU: the recurrence is "
+                "float64 and the TPU kernel compiler (Mosaic) takes "
+                "float32 only; use backend='jit'")
+        return backend
+    if backend in ("numpy", "jit"):
         return backend
     raise ValueError(f"unknown backend {backend!r} "
                      "(expected 'auto', 'numpy', 'jit' or 'pallas')")
@@ -801,9 +793,10 @@ def simulate_many(programs: list[SimProgram],
             convergence.
         backend: ``"numpy"`` (reference slot sweep), ``"jit"``
             (``jax.jit`` + ``vmap``, shape-bucketed), ``"pallas"``
-            (jit with the Pallas arbitration step), or ``"auto"``
-            (jit for groups of ≥ :data:`AUTO_JIT_MIN_BATCH` when jax is
-            importable, else numpy).  See docs/performance.md.
+            (jit with the Pallas arbitration step, interpret mode
+            only; refused on a TPU), or ``"auto"`` (jit for groups of
+            ≥ :data:`AUTO_JIT_MIN_BATCH`, else numpy).  See
+            docs/performance.md.
         classify: optional replacement for the bottleneck classifier
             (the :class:`~repro.core.engine.AnalysisService` passes a
             memoized one).

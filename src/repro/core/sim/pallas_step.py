@@ -5,16 +5,15 @@ The hottest sub-step of the vectorized sweep recurrence
 capacity accumulators with the uop's eligibility set, pick the
 least-loaded port (first index on ties, matching ``np.argmin``), and
 book the uop's cycles onto it.  ``backend="pallas"`` swaps the ``lax``
-formulation for this kernel — worthwhile on TPU fleets where the
-shard's ``[lanes, ports]`` capacity block lives in VMEM next to the
-rest of the compiled recurrence; everywhere else the kernel runs in
-interpreter mode (exact, float64-capable, slow), which is what the
-parity tests exercise.
+formulation for this kernel.
 
 The kernel processes one whole shard (``JIT_SHARD`` lanes × ``P``
-ports, a few KB) as a single block.  On real TPU hardware the float64
-sweep dtype is unavailable — run the ``jit`` driver there, or accept
-float32 (see docs/performance.md).
+ports, a few KB) as a single block, always in interpreter mode (exact,
+float64-capable, slow), which is what the parity tests exercise.  The
+TPU's kernel compiler (Mosaic) refuses float64 (``Only float32 is
+supported``), so ``simulate_many`` refuses ``backend="pallas"`` on a
+TPU before anything compiles; the ``jit`` driver is the device path
+there (see docs/performance.md).
 """
 from __future__ import annotations
 
@@ -34,8 +33,6 @@ def make_arbitration_step(n_ports: int):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    interpret = jax.default_backend() != "tpu"
-
     def kernel(cap_ref, elig_ref, cyc_ref, cap_out, pmin_out):
         cap = cap_ref[...]
         pf = jnp.where(elig_ref[...], cap, jnp.inf)
@@ -53,7 +50,7 @@ def make_arbitration_step(n_ports: int):
                 jax.ShapeDtypeStruct((port_cap.shape[0],),
                                      port_cap.dtype),
             ),
-            interpret=interpret,
+            interpret=True,
         )(port_cap, elig, cyc_upd)
 
     return step

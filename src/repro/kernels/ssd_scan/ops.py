@@ -21,8 +21,8 @@ def ssd_scan(x, da, dt, bm, cm, *, chunk: int = 128,
     assert S % Q == 0
     nc = S // Q
     xk = x.transpose(0, 2, 1, 3).reshape(B, H, nc, Q, P)
-    dak = da.transpose(0, 2, 1).reshape(B, H, nc, Q)
-    dtk = dt.transpose(0, 2, 1).reshape(B, H, nc, Q)
+    dak = da.transpose(0, 2, 1).reshape(B, H, nc, 1, Q)
+    dtk = dt.transpose(0, 2, 1).reshape(B, H, nc, 1, Q)
     bk = bm.reshape(B, nc, Q, -1)
     ck = cm.reshape(B, nc, Q, -1)
     y = ssd_scan_bhcqp(xk, dak, dtk, bk, ck, interpret=bool(interpret))

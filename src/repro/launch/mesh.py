@@ -12,7 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -32,7 +32,10 @@ def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes: the model code places arrays with
+        # with_sharding_constraint, which Explicit axes reject
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     if len(devices) > n:
         # e.g. 512 forced host devices, single-pod 256-chip mesh
         return Mesh(np.asarray(devices[:n]).reshape(shape), axes)

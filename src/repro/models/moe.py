@@ -135,9 +135,7 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig
                                              capacity)
         out = out.astype(x.dtype)
     else:
-        from repro.parallel.sharding import shard_map_compat
         from jax.sharding import PartitionSpec as P
-        shard_map, _check = shard_map_compat()
         mode = moe_sharding_mode(E)
         msize = rules.model_size
         dsize = rules.data_size
@@ -197,11 +195,11 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig
             aux = lax.pmean(aux, "model")
             return out.astype(xl.dtype), aux
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_fn, mesh=rules.mesh,
             in_specs=(w_spec, P(*t_spec)),
             out_specs=(P(*t_spec), P()),
-            **_check)
+            check_vma=False)
         out, aux = mapped(routed, x)
         return out, aux
 
@@ -236,9 +234,7 @@ def _dense_ffn_tp(params: dict, x: jax.Array, cfg: ModelConfig,
     locally, and the row-parallel partial sums leave through a bf16
     reduce-scatter back to sequence sharding — replacing auto-SPMD's
     f32 all-reduce + reshard pair (half the bytes twice over)."""
-    from repro.parallel.sharding import shard_map_compat
     from jax.sharding import PartitionSpec as P
-    shard_map, _check = shard_map_compat()
     B, S, d = x.shape
     batch_ok = B % rules.data_size == 0
     seq_sp = cfg.seq_shard_residual and S % rules.model_size == 0
@@ -261,9 +257,9 @@ def _dense_ffn_tp(params: dict, x: jax.Array, cfg: ModelConfig,
         return lax.psum(partial, "model")
 
     routed = {k: params[k] for k in w_spec}
-    return shard_map(local_fn, mesh=rules.mesh,
-                     in_specs=(w_spec, x_spec), out_specs=x_spec,
-                     **_check)(routed, x)
+    return jax.shard_map(local_fn, mesh=rules.mesh,
+                         in_specs=(w_spec, x_spec), out_specs=x_spec,
+                         check_vma=False)(routed, x)
 
 
 def dense_ffn_schema(cfg: ModelConfig, d_ff: int | None = None) -> dict:

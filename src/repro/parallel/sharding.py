@@ -290,7 +290,6 @@ def row_parallel_matmul(x, w, enabled: bool = True):
     if not enabled or rules is None or k % rules.model_size != 0 \
             or rules.stationary_weights:
         return x @ w
-    shard_map, check = shard_map_compat()
     B = x.shape[0]
     batch_ok = B % rules.data_size == 0 and B >= rules.data_size
     lead = (rules.data_axes,) if batch_ok else (None,)
@@ -300,28 +299,9 @@ def row_parallel_matmul(x, w, enabled: bool = True):
     def local_fn(wl, xl):
         return _jax.lax.psum((xl @ wl).astype(xl.dtype), "model")
 
-    return shard_map(local_fn, mesh=rules.mesh,
-                     in_specs=(P("model"), x_spec), out_specs=out_spec,
-                     **check)(w, x)
-
-
-def shard_map_compat():
-    """``jax.shard_map`` across jax versions.
-
-    Returns ``(shard_map, check_kwargs)``: the function from its current
-    home (top-level since ~0.6, ``jax.experimental.shard_map`` before)
-    and the kwargs that disable the replication check under its current
-    name (``check_vma``, formerly ``check_rep``).
-    """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    sig = inspect.signature(shard_map).parameters
-    check = {"check_vma": False} if "check_vma" in sig else \
-        {"check_rep": False}
-    return shard_map, check
+    return _jax.shard_map(local_fn, mesh=rules.mesh,
+                          in_specs=(P("model"), x_spec), out_specs=out_spec,
+                          check_vma=False)(w, x)
 
 
 def constrain(x, *logical):

@@ -85,7 +85,7 @@ class ServiceResponse:
     # degraded answer is never silently indistinguishable from a
     # full-fidelity one (docs/robustness.md)
     degraded: bool = False
-    backend_used: str = ""           # fallback rung ("" = as requested)
+    backend_used: str = ""           # rung that answered ("" = none)
     fault_trace_id: int = 0          # FaultInjector event id (0 = none)
     routed_from: str = ""            # rung the HealthRouter skipped
     #                                  pre-dispatch ("" = not routed)

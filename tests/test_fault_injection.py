@@ -47,10 +47,6 @@ from repro.core.degrade import (BreakerBoard, BreakerConfig,
 from repro.core.engine import AnalysisRequest
 from repro.core.faults import (FAULT_POINTS, FaultAbort, FaultInjector,
                                FaultPlan, FaultSpec, InjectedFault)
-from repro.core.sim import has_jax
-
-needs_jax = pytest.mark.skipif(not has_jax(),
-                               reason="jax not installed")
 
 KERNELS = {"triad_skl": pk.TRIAD_SKL_O3, "pi_o2": pk.PI_O2}
 
@@ -246,7 +242,7 @@ def test_breaker_board_logs_transitions():
 
 
 def test_validate_sims_flags_corrupt_output():
-    prog = SimpleNamespace(kernel_id="k", port_bound_cycles=2.0)
+    prog = SimpleNamespace(digest="k", port_bound_cycles=2.0)
     sim = lambda cpi: SimpleNamespace(cycles_per_iteration=cpi)  # noqa: E731
     assert validate_sims([sim(2.5)], [prog]) == []
     assert "non-finite" in validate_sims([sim(float("nan"))], [prog])[0]
@@ -254,7 +250,7 @@ def test_validate_sims_flags_corrupt_output():
     assert "diverges above" in validate_sims([sim(2.0 * 51)], [prog])[0]
     assert "diverges below" in validate_sims([sim(2.0 / 51)], [prog])[0]
     # a zero analytic bound disables the divergence guard only
-    free = SimpleNamespace(kernel_id="k", port_bound_cycles=0.0)
+    free = SimpleNamespace(digest="k", port_bound_cycles=0.0)
     assert validate_sims([sim(1000.0)], [free]) == []
 
 
@@ -295,7 +291,6 @@ def test_persistent_dispatch_fault_degrades_to_analytic_floor():
     assert states and all(s == "open" for s in states.values())
 
 
-@needs_jax
 def test_jit_failure_demotes_to_numpy_bit_identically():
     reqs = [AnalysisRequest(kernel=src, arch=arch, mode="simulate")
             for arch, src in (("skl", pk.TRIAD_SKL_O3),
@@ -336,6 +331,69 @@ def test_single_predict_tick_fault_falls_to_floor():
     assert res.degraded and res.backend_used == "analytic"
     assert res.bound_sim == 0.0 and math.isfinite(res.predicted_cycles)
     assert svc.stats.degraded_results == 1
+
+
+def _raiser(exc: BaseException):
+    def broken(*args, **kwargs):
+        raise exc
+    return broken
+
+
+@pytest.mark.parametrize("bug", [ImportError, AttributeError, TypeError,
+                                 NotImplementedError])
+def test_programming_error_in_a_rung_propagates(monkeypatch, bug):
+    """Only real faults demote: a bug inside the jit rung surfaces
+    instead of being answered quietly by numpy."""
+    import repro.core.sim.batch as batch
+
+    monkeypatch.setattr(batch, "_run_jax", _raiser(bug("rung is broken")))
+    svc = AnalysisService(sim_backend="jit")
+    with pytest.raises(bug, match="rung is broken"):
+        svc.predict_batch(_sim_reqs())
+    assert svc.stats.degraded_results == 0
+
+
+def test_programming_error_in_tick_path_propagates(monkeypatch):
+    import repro.core.sim as sim
+
+    monkeypatch.setattr(sim, "simulate",
+                        _raiser(AttributeError("tick is broken")))
+    svc = AnalysisService()
+    with pytest.raises(AttributeError, match="tick is broken"):
+        svc.predict(AnalysisRequest(kernel=pk.PI_O2, arch="skl",
+                                    mode="simulate"))
+    assert svc.stats.degraded_results == 0
+
+
+def test_device_runtime_error_demotes_to_numpy(monkeypatch):
+    from jax.errors import JaxRuntimeError
+
+    import repro.core.sim.batch as batch
+
+    monkeypatch.setattr(batch, "_run_jax", _raiser(
+        JaxRuntimeError("RESOURCE_EXHAUSTED: out of device memory")))
+    results = AnalysisService(sim_backend="jit").predict_batch(_sim_reqs())
+    clean = AnalysisService(sim_backend="numpy").predict_batch(_sim_reqs())
+    for d, c in zip(results, clean):
+        assert d.degraded and d.backend_used == "numpy"
+        assert d.bound_sim == c.bound_sim
+
+
+def test_pallas_refused_on_tpu_and_not_demoted(monkeypatch):
+    """Mosaic takes no float64: on a TPU the pallas rung raises up
+    front, and the ladder does not turn that into a jit answer."""
+    import jax
+
+    from repro.core import compile_program, extract_kernel
+    from repro.core.sim import simulate_many
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog = compile_program(extract_kernel(pk.PI_O2), "skl")
+    with pytest.raises(NotImplementedError, match="float64"):
+        simulate_many([prog], backend="pallas")
+    svc = AnalysisService(sim_backend="pallas")
+    with pytest.raises(NotImplementedError, match="float64"):
+        svc.predict_batch(_sim_reqs())
 
 
 def test_compile_fault_degrades_only_affected_cells():

@@ -22,7 +22,7 @@ from repro.core import extract_kernel, get_model
 from repro.core import paper_kernels as pk
 from repro.core.ports import PipelineParams, PortModel
 from repro.core.sim import (SimProgram, SimUop, compile_program,
-                            has_jax, simulate, simulate_many)
+                            simulate, simulate_many)
 
 RAND_MODEL = PortModel(name="rand", ports=("0", "1", "2", "3"))
 
@@ -85,7 +85,6 @@ def _assert_backends_agree(programs, params):
 # ------------------------------------------------------------------ #
 # Random differential sweep (seeded, always runs)
 # ------------------------------------------------------------------ #
-@pytest.mark.skipif(not has_jax(), reason="jax not installed")
 @pytest.mark.parametrize("seed", range(6))
 def test_random_programs_backends_agree_frontend_on(seed):
     rng = random.Random(1000 + seed)
@@ -93,7 +92,6 @@ def test_random_programs_backends_agree_frontend_on(seed):
     _assert_backends_agree(programs, FE_PARAMS)
 
 
-@pytest.mark.skipif(not has_jax(), reason="jax not installed")
 def test_random_programs_backends_agree_frontend_off():
     rng = random.Random(7)
     programs = [random_program(rng) for _ in range(8)]
@@ -122,8 +120,6 @@ def test_random_program_frontend_off_ignores_capabilities(seed):
 @settings(max_examples=20, deadline=None)
 @given(st.integers(min_value=0, max_value=2**31))
 def test_property_backends_agree(seed):
-    if not has_jax():
-        pytest.skip("jax not installed")
     rng = random.Random(seed)
     _assert_backends_agree([random_program(rng)], FE_PARAMS)
 
@@ -180,7 +176,6 @@ def test_features_off_reproduces_pre_frontend_cycles(name):
     assert sweep.converged
 
 
-@pytest.mark.skipif(not has_jax(), reason="jax not installed")
 @pytest.mark.parametrize("name", ["triad_skl", "pi_zen_O2"])
 def test_features_off_jit_matches_numpy_baseline(name):
     arch, src = PAPER_CASES[name]
@@ -195,7 +190,6 @@ def test_features_off_jit_matches_numpy_baseline(name):
 # ------------------------------------------------------------------ #
 # Front end ON: the paper kernels across all three batch backends
 # ------------------------------------------------------------------ #
-@pytest.mark.skipif(not has_jax(), reason="jax not installed")
 def test_paper_kernels_backends_agree_frontend_on():
     programs = [compile_program(extract_kernel(src), arch)
                 for arch, src in PAPER_CASES.values()]

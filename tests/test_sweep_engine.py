@@ -14,7 +14,7 @@ from repro.core import paper_kernels as pk
 from repro.core.arch.skylake import build_skylake_db
 from repro.core.arch.zen import build_zen_db
 from repro.core.scheduler import SCHEDULERS
-from repro.core.sim import (SimProgram, SimUop, compile_program, has_jax,
+from repro.core.sim import (SimProgram, SimUop, compile_program,
                             simulate, simulate_many)
 from repro.core.sim.batch import _jit_compatible, _steady_state
 
@@ -27,9 +27,6 @@ PAPER_KERNELS = {
     "pi_skl_o3": pk.PI_SKL_O3, "pi_zen_o3": pk.PI_ZEN_O3,
 }
 
-needs_jax = pytest.mark.skipif(not has_jax(), reason="jax not installed")
-
-
 def _paper_programs():
     progs = []
     for src in PAPER_KERNELS.values():
@@ -41,7 +38,6 @@ def _paper_programs():
 # ------------------------------------------------------------------ #
 # Backend parity: numpy vs jit (vs pallas) to 1e-9
 # ------------------------------------------------------------------ #
-@needs_jax
 def test_driver_parity_numpy_vs_jit_on_paper_kernels():
     progs = _paper_programs()
     rn = simulate_many(progs, backend="numpy")
@@ -53,7 +49,6 @@ def test_driver_parity_numpy_vs_jit_on_paper_kernels():
         assert n.bottleneck == j.bottleneck
 
 
-@needs_jax
 def test_driver_parity_pallas_interpret():
     """The Pallas arbitration step (interpreter mode off-TPU) must be
     arithmetically identical to the inline lax formulation."""
@@ -66,7 +61,6 @@ def test_driver_parity_pallas_interpret():
             <= 1e-9
 
 
-@needs_jax
 @pytest.mark.parametrize("scheduler", sorted(SCHEDULERS))
 @pytest.mark.parametrize("arch", ["skl", "zen"])
 def test_service_sweep_parity_all_kernels(arch, scheduler):
@@ -131,7 +125,6 @@ def _random_program(draw, db):
                       edges=tuple(edges))
 
 
-@needs_jax
 @settings(max_examples=10, deadline=None)
 @given(st.data())
 def test_property_random_mixed_arch_batches(data):
