@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import asyncio
 import hashlib
+import itertools
 import threading
 import warnings
 from concurrent.futures import ThreadPoolExecutor
@@ -56,6 +57,14 @@ from .kernel import extract_kernel
 from .machine import MachineModel
 from .ports import PortModel, Uop
 from .scheduler import SCHEDULERS, ScheduledUop
+from .spans import span
+
+#: sequence numbers of batched calls, the ``call`` stat of their spans
+_CALLS = itertools.count(1)
+#: ``ServiceStats`` fields fed from ``simulate_many``'s counters
+_SIM_COUNTERS = ("sim_lanes", "sim_slot_steps", "sim_slot_capacity",
+                 "sim_device_calls", "sim_escalated_lanes",
+                 "sim_host_lanes")
 
 
 @dataclass(frozen=True)
@@ -148,6 +157,16 @@ class ServiceStats:
     routed_groups: int = 0   # dispatch groups the HealthRouter started
     #                          below the requested rung
     probe_dispatches: int = 0   # scheduled half-open probe dispatches
+    # the compiled recurrence's work for the rung that answered each
+    # group (simulate_many's counters; docs/performance.md)
+    sim_lanes: int = 0       # lanes sent to it (first pass)
+    sim_slot_steps: int = 0  # real lanes' uop slots x iterations
+    sim_slot_capacity: int = 0  # padded slots (U x JIT_SHARD) x
+    #                             iterations, summed over shards
+    sim_device_calls: int = 0   # shard executions, escalation included
+    sim_escalated_lanes: int = 0    # lanes re-run at 4x the horizon
+    sim_host_lanes: int = 0  # lanes asked of a compiled rung that ran
+    #                          on the numpy driver as exotic
 
     def as_dict(self) -> dict[str, int]:
         d = dict(vars(self))
@@ -703,7 +722,7 @@ class AnalysisService:
             fault_trace_id=event_id)
 
     def _run_ladder(self, digest: str, progs: list, start: str,
-                    small: bool) -> tuple:
+                    small: bool, call: int) -> tuple:
         """Dispatch one machine group down the degradation ladder.
 
         Walks the sim rungs from ``start`` (``("tick",)`` for the
@@ -715,7 +734,9 @@ class AnalysisService:
         open breaker are dropped without paying a dispatch and at
         most one scheduled probe per cooldown window reaches a rung
         that is due one.  Returns ``(sims | None, backend_used,
-        degraded, dispatches, fault event id, routed_from, probe)`` —
+        degraded, counters, fault event id, routed_from, probe)``, where
+        ``counters`` are :func:`~repro.core.sim.simulate_many`'s for
+        the rung that answered (``call`` is their span stat) —
         ``sims is None`` means every rung failed and the group takes
         the analytic floor.  :class:`FaultAbort` (a simulated process
         kill) and every exception that is not a contained fault (a bug
@@ -756,7 +777,7 @@ class AnalysisService:
                 if self.faults is not None:
                     self.faults.fire("engine.dispatch", backend=rung,
                                      machine=digest)
-                counters = {"dispatches": 0}
+                counters = {"dispatches": 0, "call": call}
                 if rung == "tick":
                     sims = [simulate(p) for p in progs]
                 else:
@@ -779,8 +800,8 @@ class AnalysisService:
                 if problems:
                     raise ResultValidationError("; ".join(problems))
                 breaker.record_success()
-                return (sims, rung, demoted, counters["dispatches"],
-                        event_id, routed_from, probe)
+                return (sims, rung, demoted, counters, event_id,
+                        routed_from, probe)
             except FaultAbort:
                 raise
             except contained_faults() as exc:
@@ -789,7 +810,7 @@ class AnalysisService:
                 demoted = True
                 continue
         # the floor answered: nothing dispatched, so no probe either
-        return None, "analytic", True, 0, event_id, routed_from, False
+        return None, "analytic", True, {}, event_id, routed_from, False
 
     @staticmethod
     def _journal_lookup(session: dict | None, digest: str,
@@ -977,74 +998,87 @@ class AnalysisService:
         self._check_epoch()
         if len(requests) <= 1:
             return [self.predict(r) for r in requests]
+        call = next(_CALLS)
+        with span("repro.predict_batch", call=call):
+            return self._predict_batch(requests, parallel, backend,
+                                       _journal, call)
+
+    def _predict_batch(self, requests: Sequence[AnalysisRequest],
+                       parallel: bool, backend: str | None,
+                       _journal: dict | None,
+                       call: int) -> list[AnalysisResult]:
+        """:meth:`predict_batch`'s planner for batch call ``call``; each
+        phase is one ``repro.*`` profiler span carrying it."""
+        import dataclasses
 
         # ---- plan: dedupe on result keys -----------------------------
-        keys = [self._result_key(r) for r in requests]
-        unique: dict[tuple, AnalysisRequest] = {}
-        for key, req in zip(keys, requests):
-            unique.setdefault(key, req)
-        with self._lock:
-            done = {k: self._results[k] for k in unique
-                    if k in self._results}
-        todo = {k: r for k, r in unique.items() if k not in done}
-        with self._lock:
-            self.stats.result_hits += len(requests) - len(todo)
+        with span("repro.plan", call=call):
+            keys = [self._result_key(r) for r in requests]
+            unique: dict[tuple, AnalysisRequest] = {}
+            for key, req in zip(keys, requests):
+                unique.setdefault(key, req)
+            with self._lock:
+                done = {k: self._results[k] for k in unique
+                        if k in self._results}
+            todo = {k: r for k, r in unique.items() if k not in done}
+            with self._lock:
+                self.stats.result_hits += len(requests) - len(todo)
 
         # ---- analytic pass (also the base of every simulate cell) ----
-        analytic_reqs: dict[tuple, AnalysisRequest] = {}
-        for key, req in todo.items():
-            if req.mode == "simulate":
-                import dataclasses
-                base = dataclasses.replace(req, mode="analytic")
-                analytic_reqs[self._result_key(base)] = base
+        with span("repro.analytic", call=call):
+            analytic_reqs: dict[tuple, AnalysisRequest] = {}
+            for key, req in todo.items():
+                if req.mode == "simulate":
+                    base = dataclasses.replace(req, mode="analytic")
+                    analytic_reqs[self._result_key(base)] = base
+                else:
+                    analytic_reqs[key] = req
+            with self._lock:
+                analytic_todo = {k: r for k, r in analytic_reqs.items()
+                                 if k not in self._results}
+                # stats mirror the sequential path: each uncached cell
+                # is one miss — including the analytic base a simulate
+                # cell computes implicitly — everything else a hit
+                self.stats.result_misses += len(todo) + sum(
+                    1 for k in analytic_todo if k not in todo)
+            if parallel and len(analytic_todo) > 1:
+                with ThreadPoolExecutor(
+                        max_workers=self._max_workers) as ex:
+                    computed = list(ex.map(self._compute_analytic,
+                                           analytic_todo.values()))
             else:
-                analytic_reqs[key] = req
-        with self._lock:
-            analytic_todo = {k: r for k, r in analytic_reqs.items()
-                             if k not in self._results}
-            # stats mirror the sequential path: each uncached cell is
-            # one miss — including the analytic base a simulate cell
-            # computes implicitly — everything else a hit
-            self.stats.result_misses += len(todo) + sum(
-                1 for k in analytic_todo if k not in todo)
-        if parallel and len(analytic_todo) > 1:
-            with ThreadPoolExecutor(max_workers=self._max_workers) as ex:
-                computed = list(ex.map(self._compute_analytic,
-                                       analytic_todo.values()))
-        else:
-            computed = [self._compute_analytic(r)
-                        for r in analytic_todo.values()]
-        computed = [self._apply_ecm(res, r)
-                    for res, r in zip(computed, analytic_todo.values())]
-        with self._lock:
-            for k, res in zip(analytic_todo, computed):
-                self._results.setdefault(k, res)
+                computed = [self._compute_analytic(r)
+                            for r in analytic_todo.values()]
+            computed = [self._apply_ecm(res, r)
+                        for res, r in zip(computed, analytic_todo.values())]
+            with self._lock:
+                for k, res in zip(analytic_todo, computed):
+                    self._results.setdefault(k, res)
 
         # ---- grouped simulation dispatch -----------------------------
         sim_cells = {k: r for k, r in todo.items()
                      if r.mode == "simulate"}
-        if sim_cells:
-            sim_keys = {k: (self._arch.resolve(r.arch),
-                            self._kernel_id(r))
-                        for k, r in sim_cells.items()}
-            # sim_key -> fault event id for cells the ladder bottomed
-            # out on (compile fault or every sim rung exhausted): they
-            # get the analytic floor in the combine loop below
-            floor_cells: dict[tuple, int] = {}
-            # sim_key -> (routed_from, probe) for floor cells the
-            # router sent straight to the floor (every rung unhealthy)
-            floor_route: dict[tuple, tuple[str, bool]] = {}
-            with self._lock:
-                missing = {sk: r for k, r in sim_cells.items()
-                           if (sk := sim_keys[k]) not in self._sim_cache}
-            if missing:
-                from .sim import AUTO_JIT_MIN_BATCH
-                from .sim.batch import _resolve_backend
-                chosen = backend or self.sim_backend
-                # compile per request, containing injected compile
-                # faults per cell (a cell whose program cannot compile
-                # degrades alone; the rest of its group still simulates)
-                compiled: dict[tuple, tuple[str, object]] = {}
+        sim_keys = {k: (self._arch.resolve(r.arch), self._kernel_id(r))
+                    for k, r in sim_cells.items()}
+        # sim_key -> fault event id for cells the ladder bottomed out on
+        # (compile fault or every sim rung exhausted): they get the
+        # analytic floor in the combine loop below
+        floor_cells: dict[tuple, int] = {}
+        # sim_key -> (routed_from, probe) for floor cells the router
+        # sent straight to the floor (every rung unhealthy)
+        floor_route: dict[tuple, tuple[str, bool]] = {}
+        with self._lock:
+            missing = {sk: r for k, r in sim_cells.items()
+                       if (sk := sim_keys[k]) not in self._sim_cache}
+        if missing:
+            from .sim import AUTO_JIT_MIN_BATCH
+            from .sim.batch import _resolve_backend
+            chosen = backend or self.sim_backend
+            # compile per request, containing injected compile faults
+            # per cell (a cell whose program cannot compile degrades
+            # alone; the rest of its group still simulates)
+            compiled: dict[tuple, tuple[str, object]] = {}
+            with span("repro.compile_programs", call=call):
                 for sk, r in missing.items():
                     machine = self.resolve_machine(r.arch)
                     try:
@@ -1056,54 +1090,24 @@ class AnalysisService:
                         floor_cells[sk] = exc.event_id
                         with self._lock:
                             self.stats.degraded_results += 1
-                # the small-batch tick-loop decision and the "auto"
-                # rung both resolve on the *total* missing count, as
-                # the single simulate_many call they replace did
-                small = (chosen == "auto"
-                         and len(compiled) < AUTO_JIT_MIN_BATCH)
-                start = chosen if chosen != "auto" else \
-                    _resolve_backend("auto", len(compiled))
-                groups: dict[str, list[tuple]] = {}
-                for sk, (digest, _prog) in compiled.items():
-                    groups.setdefault(digest, []).append(sk)
-                for digest, sks in groups.items():
-                    progs = [compiled[sk][1] for sk in sks]
-                    replay = self._journal_lookup(_journal, digest, progs)
-                    if replay is not None:
-                        sims, backend_used, degraded, event_id = replay
-                        dispatches = 0
-                        routed_from, probe = "", False
-                        with self._lock:
-                            self.stats.journal_hits += 1
-                    else:
-                        sims, backend_used, degraded, dispatches, \
-                            event_id, routed_from, probe = \
-                            self._run_ladder(digest, progs, start, small)
-                        self._journal_record(_journal, digest, progs,
-                                             sims, backend_used, degraded)
-                    with self._lock:
-                        if sims is None:
-                            # every sim rung failed or was breaker-open:
-                            # the whole group takes the analytic floor
-                            self.stats.degraded_results += len(sks)
-                            for sk in sks:
-                                floor_cells.setdefault(sk, event_id)
-                                if routed_from:
-                                    floor_route[sk] = (routed_from, False)
-                            continue
-                        if replay is None:
-                            self.stats.sim_runs += len(progs)
-                            self.stats.sim_group_dispatches += dispatches
-                        for sk, sim in zip(sks, sims):
-                            self._sim_cache.setdefault(sk, sim)
-                        if degraded:
-                            self.stats.degraded_results += len(sks)
-                        for sk in sks:
-                            self._sim_provenance[sk] = (
-                                backend_used, degraded, event_id,
-                                routed_from, probe)
-            # combine analytic base + simulation per cell
-            import dataclasses
+            # the small-batch tick-loop decision and the "auto" rung
+            # both resolve on the *total* missing count, as the single
+            # simulate_many call they replace did
+            small = (chosen == "auto"
+                     and len(compiled) < AUTO_JIT_MIN_BATCH)
+            start = chosen if chosen != "auto" else \
+                _resolve_backend("auto", len(compiled))
+            groups: dict[str, list[tuple]] = {}
+            for sk, (digest, _prog) in compiled.items():
+                groups.setdefault(digest, []).append(sk)
+            for digest, sks in groups.items():
+                with span("repro.dispatch", call=call):
+                    self._dispatch_group(digest, sks, compiled, start,
+                                         small, _journal, call,
+                                         floor_cells, floor_route)
+
+        # combine analytic base + simulation per cell, then gather
+        with span("repro.combine", call=call):
             for k, req in sim_cells.items():
                 base_key = self._result_key(
                     dataclasses.replace(req, mode="analytic"))
@@ -1134,13 +1138,59 @@ class AnalysisService:
                 with self._lock:
                     self._results.setdefault(k, res)
 
-        out = []
-        for key, req in zip(keys, requests):
-            with self._lock:
-                res = self._results.get(key)
-            # concurrent invalidation between fill and gather: recompute
-            out.append(res if res is not None else self.predict(req))
+            out = []
+            for key, req in zip(keys, requests):
+                with self._lock:
+                    res = self._results.get(key)
+                # concurrent invalidation between fill and gather:
+                # recompute
+                out.append(res if res is not None else self.predict(req))
         return out
+
+    def _dispatch_group(self, digest: str, sks: list[tuple],
+                        compiled: dict, start: str, small: bool,
+                        _journal: dict | None, call: int,
+                        floor_cells: dict, floor_route: dict) -> None:
+        """Simulate one machine group (journal replay or the ladder)
+        into the sim cache, recording floor cells for the combine."""
+        progs = [compiled[sk][1] for sk in sks]
+        replay = self._journal_lookup(_journal, digest, progs)
+        if replay is not None:
+            sims, backend_used, degraded, event_id = replay
+            counters: dict = {}
+            routed_from, probe = "", False
+            with self._lock:
+                self.stats.journal_hits += 1
+        else:
+            sims, backend_used, degraded, counters, event_id, \
+                routed_from, probe = self._run_ladder(digest, progs, start,
+                                                      small, call)
+            self._journal_record(_journal, digest, progs, sims,
+                                 backend_used, degraded)
+        with self._lock:
+            if sims is None:
+                # every sim rung failed or was breaker-open: the whole
+                # group takes the analytic floor
+                self.stats.degraded_results += len(sks)
+                for sk in sks:
+                    floor_cells.setdefault(sk, event_id)
+                    if routed_from:
+                        floor_route[sk] = (routed_from, False)
+                return
+            if replay is None:
+                self.stats.sim_runs += len(progs)
+                self.stats.sim_group_dispatches += \
+                    counters.get("dispatches", 0)
+                for name in _SIM_COUNTERS:
+                    setattr(self.stats, name, getattr(self.stats, name)
+                            + counters.get(name, 0))
+            for sk, sim in zip(sks, sims):
+                self._sim_cache.setdefault(sk, sim)
+            if degraded:
+                self.stats.degraded_results += len(sks)
+            for sk in sks:
+                self._sim_provenance[sk] = (backend_used, degraded,
+                                            event_id, routed_from, probe)
 
     async def predict_async(self, request: AnalysisRequest, *,
                             timeout: float | None = None,

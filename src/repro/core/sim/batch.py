@@ -88,6 +88,7 @@ from typing import Callable
 import numpy as np
 
 from ..ports import PipelineParams
+from ..spans import span
 from .pipeline import (DEFAULT_PARAMS, SimProgram, SimResult, _classify,
                        frontend_schedule)
 
@@ -680,36 +681,45 @@ def _empty_program(model) -> SimProgram:
                       latency=(), edges=())
 
 
-def _run_jax(programs: list[SimProgram], ports: tuple[str, ...],
-             params: PipelineParams, n_iterations: int,
-             flavor: str) -> np.ndarray:
-    """Shard + run the compiled recurrence; agrees with
+def _pack_shards(programs: list[SimProgram], ports: tuple[str, ...],
+                 params: PipelineParams, n_iterations: int) -> list[dict]:
+    """Cut ``programs`` into ``JIT_SHARD``-lane shards, the last padded
+    with empty lanes, and pack each for the compiled recurrence."""
+    model = programs[0].model
+    shards = []
+    for s in range(0, len(programs), JIT_SHARD):
+        chunk = programs[s:s + JIT_SHARD]
+        chunk = chunk + [_empty_program(model)] * (JIT_SHARD - len(chunk))
+        shards.append(_pack_lean(chunk, ports, params, n_iterations))
+    return shards
+
+
+def _run_jax(shards: list[dict], n_lanes: int, ports: tuple[str, ...],
+             params: PipelineParams, n_iterations: int, flavor: str,
+             meta: dict | None = None) -> np.ndarray:
+    """Run packed shards on the compiled recurrence and return the
+    first ``n_lanes`` lanes' retire trajectories; agrees with
     :func:`_run_numpy` to 1e-9 because it executes the identical
-    arithmetic in float64 (``jax.enable_x64``)."""
+    arithmetic in float64 (``jax.enable_x64``).  Each shard (argument
+    transfer, execution, fetch) is one ``repro.sim.shard`` span on the
+    thread that runs it, carrying ``meta``."""
     import jax
     import jax.numpy as jnp
 
-    B = len(programs)
-    model = programs[0].model
-    n_shards = -(-B // JIT_SHARD)
-    shards = []
-    for s in range(n_shards):
-        chunk = programs[s * JIT_SHARD:(s + 1) * JIT_SHARD]
-        chunk = chunk + [_empty_program(model)] * (JIT_SHARD - len(chunk))
-        shards.append(_pack_lean(chunk, ports, params, n_iterations))
-
-    def run_shard(pk: dict) -> np.ndarray:
-        with jax.enable_x64(True):
+    def run_shard(s: int) -> np.ndarray:
+        pk = shards[s]
+        with span("repro.sim.shard", **(meta or {}), shard=s, U=pk["U"],
+                  E=pk["E"], T=n_iterations), jax.enable_x64(True):
             fn = _compiled_run(pk["U"], pk["E"], len(ports),
                                n_iterations, params, flavor)
             args = [jnp.asarray(pk[k]) for k in _LEAN_ARGS]
             return np.asarray(fn(*args))
 
     if len(shards) == 1:
-        outs = [run_shard(shards[0])]
+        outs = [run_shard(0)]
     else:
-        outs = list(_pool().map(run_shard, shards))
-    return np.concatenate(outs, axis=0)[:B]
+        outs = list(_pool().map(run_shard, range(len(shards))))
+    return np.concatenate(outs, axis=0)[:n_lanes]
 
 
 # --------------------------------------------------------------------------
@@ -800,11 +810,24 @@ def simulate_many(programs: list[SimProgram],
         classify: optional replacement for the bottleneck classifier
             (the :class:`~repro.core.engine.AnalysisService` passes a
             memoized one).
-        counters: optional dict whose ``"dispatches"`` entry is
-            incremented once per driver invocation actually issued
-            (split groups count each sub-invocation; a sharded jit
-            dispatch counts once) — the engine surfaces this as
-            ``stats.sim_group_dispatches``.
+        counters: optional dict the engine surfaces in
+            :class:`~repro.core.engine.ServiceStats`.  ``"dispatches"``
+            is incremented once per driver invocation actually issued
+            (a group split between the reference driver and the
+            compiled one counts each; a sharded jit dispatch counts
+            once; the escalation pass does not count):
+            ``stats.sim_group_dispatches``.  The ``sim_*`` entries
+            count the compiled recurrence's work, escalation included
+            unless noted: ``sim_lanes`` (lanes sent to it, first pass),
+            ``sim_device_calls`` (shard executions), ``sim_slot_steps``
+            (real lanes' uop slots × iterations, summed over shards),
+            ``sim_slot_capacity`` (the same for the shards' padded
+            ``U × JIT_SHARD`` slots), ``sim_escalated_lanes`` (lanes
+            re-run at 4× the horizon, any driver) and
+            ``sim_host_lanes`` (lanes asked of a compiled backend that
+            ran on the reference driver as exotic, first pass).  A
+            ``"call"`` entry, when present, is attached to every
+            ``repro.sim.*`` profiler span as its ``call`` stat.
     """
     classify = classify or _classify
     groups: dict[tuple, _Group] = {}
@@ -826,6 +849,12 @@ def simulate_many(programs: list[SimProgram],
     return out  # type: ignore[return-value]
 
 
+def _count(counters: dict | None, **increments: int) -> None:
+    if counters is not None:
+        for key, n in increments.items():
+            counters[key] = counters.get(key, 0) + n
+
+
 def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
                     params: PipelineParams, n_iterations: int,
                     warmup: int, max_period: int, backend: str,
@@ -835,35 +864,47 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
     if max((len(p.uops) for p in programs), default=0) == 0:
         return [SimResult(0.0, 0, True, "empty", 0.0, {}, params)
                 for _ in programs]
+    # the planner call these spans belong to, as their ``call`` stat
+    meta = {"call": counters["call"]} if counters and "call" in counters \
+        else {}
+    B, T = len(programs), n_iterations
+    host = list(range(B))        # lanes on the numpy reference driver
+    lanes: list[int] = []        # lanes on the compiled recurrence
+    shards: list[dict] = []
     if backend != "numpy":
-        ok = [_jit_compatible([p], params) for p in programs]
-        if not all(ok):
+        with span("repro.sim.pack", **meta):
             # exotic programs (non-contiguous slots / iteration larger
-            # than a window) take the reference path — individually,
+            # than a window) take the reference driver, individually,
             # so one of them does not downgrade the whole group
-            exotic = [p for p, k in zip(programs, ok) if not k]
-            rest = [p for p, k in zip(programs, ok) if k]
-            sub = _simulate_group(exotic, ports, params, n_iterations,
-                                  warmup, max_period, "numpy",
-                                  classify, counters, _grown=_grown)
-            out = iter(sub)
-            if rest:
-                sub2 = iter(_simulate_group(
-                    rest, ports, params, n_iterations, warmup,
-                    max_period, backend, classify, counters,
-                    _grown=_grown))
-                return [next(out) if not k else next(sub2)
-                        for k in ok]
-            return sub
-    if counters is not None:
-        counters["dispatches"] = counters.get("dispatches", 0) + 1
-    if backend == "numpy":
-        iter_end = _run_numpy(_pack(programs, ports, params),
-                              n_iterations)
-    else:
-        iter_end = _run_jax(programs, ports, params, n_iterations,
-                            "pallas" if backend == "pallas" else "lax")
-    cpi, converged = _steady_state(iter_end, warmup, max_period)
+            ok = [_jit_compatible([p], params) for p in programs]
+            host = [b for b in range(B) if not ok[b]]
+            lanes = [b for b in range(B) if ok[b]]
+            if any(programs[b].uops for b in lanes):
+                shards = _pack_shards([programs[b] for b in lanes], ports,
+                                      params, T)
+    if not _grown:
+        # one dispatch per driver invocation (the reference driver for
+        # exotic lanes and the compiled one each count); the
+        # escalation pass below is not a dispatch of its own
+        _count(counters, dispatches=bool(host) + bool(shards),
+               sim_lanes=len(lanes) if shards else 0,
+               sim_host_lanes=len(host) if backend != "numpy" else 0)
+    iter_end = np.zeros((B, T))
+    if host:
+        with span("repro.sim.numpy", **meta):
+            iter_end[host] = _run_numpy(
+                _pack([programs[b] for b in host], ports, params), T)
+    if shards:
+        _count(counters, sim_device_calls=len(shards),
+               sim_slot_steps=T * sum(int(pk["n_uops"].sum())
+                                      for pk in shards),
+               sim_slot_capacity=T * JIT_SHARD * sum(pk["U"]
+                                                     for pk in shards))
+        iter_end[lanes] = _run_jax(
+            shards, len(lanes), ports, params, T,
+            "pallas" if backend == "pallas" else "lax", meta)
+    with span("repro.sim.steady_state", **meta):
+        cpi, converged = _steady_state(iter_end, warmup, max_period)
 
     # one escalation pass: a lane whose transient outlasts the horizon
     # (e.g. a divider backlog that takes ~scheduler_size iterations to
@@ -874,29 +915,32 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
         retry_idx = [b for b, prog in enumerate(programs)
                      if prog.uops and not converged[b]]
         if retry_idx:
-            sub = _simulate_group(
-                [programs[b] for b in retry_idx], ports, params,
-                4 * n_iterations, warmup, max_period, backend,
-                classify, None, _grown=True)
+            _count(counters, sim_escalated_lanes=len(retry_idx))
+            with span("repro.sim.escalate", **meta):
+                sub = _simulate_group(
+                    [programs[b] for b in retry_idx], ports, params,
+                    4 * n_iterations, warmup, max_period, backend,
+                    classify, counters, _grown=True)
             retry = dict(zip(retry_idx, sub))
 
     results = []
-    for b, prog in enumerate(programs):
-        if not prog.uops:
-            results.append(SimResult(0.0, 0, True, "empty", 0.0, {},
-                                     params))
-            continue
-        if b in retry:
-            results.append(retry[b])
-            continue
-        sched = frontend_schedule(prog, params)
-        fe = sched.n_slots / params.issue_width
-        results.append(SimResult(
-            cycles_per_iteration=float(cpi[b]),
-            iterations=n_iterations, converged=bool(converged[b]),
-            bottleneck=classify(float(cpi[b]), fe,
-                                prog.port_bound_cycles, sched.cpi,
-                                sched.mode),
-            frontend_cycles=fe, port_busy={}, params=params,
-            delivery_cycles=sched.cpi, fe_mode=sched.mode))
+    with span("repro.sim.finish", **meta):
+        for b, prog in enumerate(programs):
+            if not prog.uops:
+                results.append(SimResult(0.0, 0, True, "empty", 0.0, {},
+                                         params))
+                continue
+            if b in retry:
+                results.append(retry[b])
+                continue
+            sched = frontend_schedule(prog, params)
+            fe = sched.n_slots / params.issue_width
+            results.append(SimResult(
+                cycles_per_iteration=float(cpi[b]),
+                iterations=n_iterations, converged=bool(converged[b]),
+                bottleneck=classify(float(cpi[b]), fe,
+                                    prog.port_bound_cycles, sched.cpi,
+                                    sched.mode),
+                frontend_cycles=fe, port_busy={}, params=params,
+                delivery_cycles=sched.cpi, fe_mode=sched.mode))
     return results

@@ -211,6 +211,56 @@ def test_planner_falls_back_for_exotic_programs():
     assert out[1].cycles_per_iteration == pytest.approx(9.0)
 
 
+def test_counters_count_shards_slots_and_escalation():
+    """70 lanes make two 64-lane shards; the one lane still in its
+    transient at 16 iterations re-runs alone at 64, and its shard
+    counts in the calls and the slots."""
+    pi = compile_program(extract_kernel(pk.PI_O1), SKL)         # 15 uops
+    triad = compile_program(extract_kernel(pk.TRIAD_SKL_O3), SKL)  # 9
+    progs = [triad] + [pi] * 69
+    counters = {}
+    out = simulate_many(progs, backend="jit", n_iterations=16,
+                        counters=counters)
+    assert out == simulate_many(progs, backend="numpy", n_iterations=16)
+    assert out[0].iterations == 64 and out[1].iterations == 16
+    # shapes are bucketed to multiples of 4: U = 16, then 12 for triad
+    assert counters == {
+        "dispatches": 1, "sim_lanes": 70, "sim_host_lanes": 0,
+        "sim_device_calls": 2 + 1, "sim_escalated_lanes": 1,
+        "sim_slot_capacity": 2 * 16 * 64 * 16 + 12 * 64 * 64,
+        "sim_slot_steps": (9 + 69 * 15) * 16 + 9 * 64}
+
+
+def test_counters_of_exotic_lanes():
+    model = SKL.model
+    exotic = SimProgram(
+        model=model, n_instructions=2,
+        uops=(SimUop(0, ("0",)), SimUop(1, ("1",)), SimUop(0, ("0",))),
+        latency=(1.0, 1.0), edges=())
+    paper = compile_program(extract_kernel(pk.PI_O1), SKL)
+    counters = {}
+    simulate_many([exotic, paper], backend="jit", counters=counters)
+    # the reference driver and the compiled one are a dispatch each
+    assert counters["dispatches"] == 2
+    assert counters["sim_host_lanes"] == 1
+    assert counters["sim_lanes"] == counters["sim_device_calls"] == 1
+
+
+def test_planner_surfaces_sim_counters_in_stats():
+    svc = AnalysisService(sim_backend="jit")
+    svc.sweep(PAPER_KERNELS, archs=("skl",), mode="simulate")
+    progs = [compile_program(extract_kernel(src), SKL)
+             for src in PAPER_KERNELS.values()]
+    uops = [len(p.uops) for p in progs]
+    U = -(-max(uops) // 4) * 4
+    s = svc.stats
+    assert s.sim_group_dispatches == 1
+    assert (s.sim_lanes, s.sim_device_calls, s.sim_host_lanes,
+            s.sim_escalated_lanes) == (len(progs), 1, 0, 0)
+    assert s.sim_slot_steps == sum(uops) * 96
+    assert s.sim_slot_capacity == U * 64 * 96
+
+
 def test_sim_program_digest_is_content_addressed():
     p1 = compile_program(extract_kernel(pk.PI_O1), SKL)
     p2 = compile_program(extract_kernel(pk.PI_O1), SKL)
