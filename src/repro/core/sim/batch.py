@@ -386,7 +386,9 @@ def _run_numpy(pk: _Packed, n_iterations: int) -> np.ndarray:
 # --------------------------------------------------------------------------
 
 #: lanes per compiled shard: small enough that the per-step working set
-#: stays cache-resident, large enough to amortize dispatch; every batch
+#: stays cache-resident, large enough to amortize dispatch; a group's
+#: lanes are sorted by (uops, edges) before they are cut into shards, so
+#: a shard pads only to the longest of lanes of like length; every batch
 #: is padded (with empty lanes) to a multiple of this, so one compiled
 #: executable per (shape bucket, machine) serves all sweep sizes
 JIT_SHARD = 64
@@ -428,9 +430,12 @@ def _jit_compatible(programs: list[SimProgram],
     return True
 
 
-def _pack_lean(programs: list[SimProgram], ports: tuple[str, ...],
-               params: PipelineParams, n_iterations: int) -> dict:
-    """Pack one shard for the compiled recurrence.
+def _pack_lean(programs: list[SimProgram],
+               edge_lists: list[list[tuple[int, int, float, bool]]],
+               ports: tuple[str, ...], params: PipelineParams,
+               n_iterations: int) -> dict:
+    """Pack one shard for the compiled recurrence; ``edge_lists`` holds
+    each program's :func:`_composed_edges`.
 
     Slot-major ``[U, B]`` layout (scan consumes leading-axis slices);
     window-gate booleans and ring index bases are precomputed here
@@ -440,7 +445,6 @@ def _pack_lean(programs: list[SimProgram], ports: tuple[str, ...],
     P = len(ports)
     T = n_iterations
     pindex = {p: i for i, p in enumerate(ports)}
-    edge_lists = [_composed_edges(p) for p in programs]
     U = _bucket(max(max((len(p.uops) for p in programs), default=0), 1))
     E = _bucket(max(max((len(es) for es in edge_lists), default=0), 1))
 
@@ -681,16 +685,22 @@ def _empty_program(model) -> SimProgram:
                       latency=(), edges=())
 
 
-def _pack_shards(programs: list[SimProgram], ports: tuple[str, ...],
-                 params: PipelineParams, n_iterations: int) -> list[dict]:
-    """Cut ``programs`` into ``JIT_SHARD``-lane shards, the last padded
-    with empty lanes, and pack each for the compiled recurrence."""
+def _pack_shards(programs: list[SimProgram],
+                 edge_lists: list[list[tuple[int, int, float, bool]]],
+                 ports: tuple[str, ...], params: PipelineParams,
+                 n_iterations: int) -> list[dict]:
+    """Cut ``programs`` (sorted by the caller by uop and edge count, so
+    lanes of like length share a shard and its padded ``U`` and ``E``)
+    into ``JIT_SHARD``-lane shards, the last padded with empty lanes,
+    and pack each for the compiled recurrence."""
     model = programs[0].model
     shards = []
     for s in range(0, len(programs), JIT_SHARD):
-        chunk = programs[s:s + JIT_SHARD]
-        chunk = chunk + [_empty_program(model)] * (JIT_SHARD - len(chunk))
-        shards.append(_pack_lean(chunk, ports, params, n_iterations))
+        chunk, edges = programs[s:s + JIT_SHARD], edge_lists[s:s + JIT_SHARD]
+        pad = JIT_SHARD - len(chunk)
+        shards.append(_pack_lean(chunk + [_empty_program(model)] * pad,
+                                 edges + [[]] * pad, ports, params,
+                                 n_iterations))
     return shards
 
 
@@ -880,7 +890,15 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
             host = [b for b in range(B) if not ok[b]]
             lanes = [b for b in range(B) if ok[b]]
             if any(programs[b].uops for b in lanes):
-                shards = _pack_shards([programs[b] for b in lanes], ports,
+                # lanes of like length share a shard, so few scan steps
+                # are padding; lanes are independent, so the order
+                # changes no answer, and ``iter_end[lanes]`` below puts
+                # the trajectories back in input order
+                edges = {b: _composed_edges(programs[b]) for b in lanes}
+                lanes.sort(key=lambda b: (len(programs[b].uops),
+                                          len(edges[b])))
+                shards = _pack_shards([programs[b] for b in lanes],
+                                      [edges[b] for b in lanes], ports,
                                       params, T)
     if not _grown:
         # one dispatch per driver invocation (the reference driver for

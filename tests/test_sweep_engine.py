@@ -1,6 +1,8 @@
 """JIT-compiled vectorized sweep engine: numpy/jit/pallas backend
 parity (1e-9), the grouped predict_batch/sweep planner, memoized
 preprocessing counters, and the bounded steady-state detector."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,7 +18,9 @@ from repro.core.arch.zen import build_zen_db
 from repro.core.scheduler import SCHEDULERS
 from repro.core.sim import (SimProgram, SimUop, compile_program,
                             simulate, simulate_many)
-from repro.core.sim.batch import _jit_compatible, _steady_state
+from repro.core.sim import batch
+from repro.core.sim.batch import (JIT_SHARD, _bucket, _composed_edges,
+                                  _jit_compatible, _steady_state)
 
 SKL = build_skylake_db()
 ZEN = build_zen_db()
@@ -229,6 +233,105 @@ def test_counters_count_shards_slots_and_escalation():
         "sim_device_calls": 2 + 1, "sim_escalated_lanes": 1,
         "sim_slot_capacity": 2 * 16 * 64 * 16 + 12 * 64 * 64,
         "sim_slot_steps": (9 + 69 * 15) * 16 + 9 * 64}
+
+
+# ------------------------------------------------------------------ #
+# Length-sorted sharding: shards of like-length lanes, input order kept
+# ------------------------------------------------------------------ #
+def _seeded_body(rng, n_uops):
+    """A contiguous loop body of ``n_uops`` uops on the Skylake ports."""
+    model = SKL.model
+    uops, latency = [], []
+    while len(uops) < n_uops:
+        idx = len(latency)
+        for _ in range(min(int(rng.integers(1, 3)), n_uops - len(uops))):
+            ports = rng.choice(model.ports, size=int(rng.integers(1, 3)),
+                               replace=False)
+            uops.append(SimUop(idx, tuple(sorted(ports)),
+                               float(rng.integers(1, 3))))
+        latency.append(float(rng.integers(1, 6)))
+    n_instr = len(latency)
+    edges = []
+    for _ in range(int(rng.integers(0, n_instr + 1))):
+        src, dst = sorted(int(i) for i in rng.integers(0, n_instr, 2))
+        w, wrap = float(rng.integers(0, 4)), bool(rng.integers(2))
+        edges.append((src, dst, w, wrap or src == dst))
+    return SimProgram(model=model, n_instructions=n_instr,
+                      uops=tuple(uops), latency=tuple(latency),
+                      edges=tuple(edges))
+
+
+@functools.lru_cache(maxsize=None)
+def _heavy_tailed_batch():
+    """201 lanes in drawn (unsorted) order: log-normal lengths (median
+    6 uops, sigma 0.75, 1-48), one exotic lane at position 57, and one
+    lane (seeded) still in its transient at 96 iterations."""
+    rng = np.random.default_rng(1)
+    lengths = np.clip(np.rint(rng.lognormal(np.log(6), 0.75, 200)), 1, 48)
+    progs = [_seeded_body(rng, int(n)) for n in lengths]
+    exotic = SimProgram(
+        model=SKL.model, n_instructions=2,
+        uops=(SimUop(0, ("0",)), SimUop(1, ("1",)), SimUop(0, ("0",))),
+        latency=(1.0, 1.0), edges=())
+    progs.insert(57, exotic)
+    counters = {}
+    out = simulate_many(progs, backend="jit", counters=counters)
+    return progs, out, counters
+
+
+def _capacity(progs, T):
+    """``sim_slot_capacity`` of one pass over ``progs`` in this order."""
+    return T * JIT_SHARD * sum(
+        _bucket(max(len(p.uops) for p in progs[s:s + JIT_SHARD]))
+        for s in range(0, len(progs), JIT_SHARD))
+
+
+def test_sorted_shards_match_numpy_in_input_order():
+    progs, out, counters = _heavy_tailed_batch()
+    assert len(progs) >= 192
+    assert out == simulate_many(progs, backend="numpy")
+    assert [r.iterations for r in out].count(4 * 96) == 1
+    assert counters["sim_host_lanes"] == 1
+    assert counters["sim_escalated_lanes"] == 1
+    assert counters["sim_lanes"] == len(progs) - 1
+
+
+def test_sorted_shards_scan_fewer_padded_slots():
+    progs, out, counters = _heavy_tailed_batch()
+    params = SKL.model.pipeline
+    lanes = [p for p in progs if _jit_compatible([p], params)]
+    grown = [p for p, r in zip(progs, out)
+             if r.iterations == 4 * 96 and _jit_compatible([p], params)]
+
+    def by_length(ps):
+        return sorted(ps, key=lambda p: (len(p.uops),
+                                         len(_composed_edges(p))))
+
+    assert counters["sim_slot_capacity"] == \
+        _capacity(by_length(lanes), 96) + _capacity(by_length(grown), 384)
+    assert counters["sim_slot_capacity"] < \
+        _capacity(lanes, 96) + _capacity(grown, 384)
+
+
+def test_one_shard_batch_keeps_its_shape(monkeypatch):
+    """At most 64 lanes make one shard, packed to the (U, E) of the
+    whole batch, as in arrival order."""
+    progs = _heavy_tailed_batch()[0][:64]
+    params = SKL.model.pipeline
+    lanes = [p for p in progs if _jit_compatible([p], params)]
+    pack_shards, shapes = batch._pack_shards, []
+
+    def spy(*args):
+        shards = pack_shards(*args)
+        shapes.extend((pk["U"], pk["E"]) for pk in shards)
+        return shards
+
+    monkeypatch.setattr(batch, "_pack_shards", spy)
+    out = simulate_many(progs, backend="jit")
+    assert all(r.iterations == 96 for r in out)
+    assert shapes == [(
+        _bucket(max(len(p.uops) for p in lanes)),
+        _bucket(max(len(_composed_edges(p)) for p in lanes)))]
 
 
 def test_counters_of_exotic_lanes():

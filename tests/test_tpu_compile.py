@@ -24,7 +24,8 @@ from repro.configs import get_config
 from repro.core import compile_program, extract_kernel, get_model
 from repro.core import paper_kernels as pk
 from repro.core.sim.batch import (JIT_SHARD, _LEAN_ARGS, _compiled_run,
-                                  _empty_program, _pack_lean)
+                                  _composed_edges, _empty_program,
+                                  _pack_lean)
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -75,7 +76,8 @@ def test_lax_recurrence_compiles_in_float64(one_chip, arch, kernels, T):
     model = get_model(arch)
     progs = [compile_program(extract_kernel(src), arch) for src in kernels]
     progs += [_empty_program(progs[0].model)] * (JIT_SHARD - len(progs))
-    shard = _pack_lean(progs, model.ports, model.pipeline, T)
+    shard = _pack_lean(progs, [_composed_edges(p) for p in progs],
+                       model.ports, model.pipeline, T)
     with jax.enable_x64(True):
         args = _shapes(one_chip, *((np.shape(shard[k]),
                                     np.asarray(shard[k]).dtype)
