@@ -8,8 +8,8 @@ never goes away.  The rung sequence is
 
     pallas -> jit -> numpy -> analytic-only
 
-(`tick` — the per-program reference interpreter used for small batches
-— is its own single-rung ladder above the analytic floor).
+(`tick` — the per-program reference interpreter that answers a single
+``predict`` — is its own single-rung ladder above the analytic floor).
 
 Per-(machine digest x backend) :class:`CircuitBreaker` state machines
 stop the engine from hammering a rung that keeps failing:
@@ -57,17 +57,12 @@ LADDER: tuple[str, ...] = ("pallas", "jit", "numpy")
 
 
 def ladder_from(backend: str) -> tuple[str, ...]:
-    """The sim rungs at or below ``backend``.
-
-    ``tick`` (the small-batch reference interpreter) has no cheaper sim
-    rung — its only fallback is the analytic floor."""
-    if backend == "tick":
-        return ("tick",)
+    """The sim rungs at or below ``backend``."""
     try:
         i = LADDER.index(backend)
     except ValueError:
         raise ValueError(f"unknown sim backend {backend!r}; "
-                         f"known: {', '.join(LADDER)} or 'tick'") from None
+                         f"known: {', '.join(LADDER)}") from None
     return LADDER[i:]
 
 

@@ -722,11 +722,10 @@ class AnalysisService:
             fault_trace_id=event_id)
 
     def _run_ladder(self, digest: str, progs: list, start: str,
-                    small: bool, call: int) -> tuple:
+                    call: int) -> tuple:
         """Dispatch one machine group down the degradation ladder.
 
-        Walks the sim rungs from ``start`` (``("tick",)`` for the
-        small-batch reference loop), skipping rungs whose circuit
+        Walks the sim rungs from ``start``, skipping rungs whose circuit
         breaker is open, validating every rung's output, and demoting
         on any contained fault (:func:`~.degrade.contained_faults`).
         When a :class:`HealthRouter` is
@@ -743,9 +742,9 @@ class AnalysisService:
         or a bad request) propagate."""
         import dataclasses
 
-        from .sim import simulate, simulate_many
+        from .sim import simulate_many
 
-        rungs = ("tick",) if small else ladder_from(start)
+        rungs = ladder_from(start)
         routed_from, probe = "", False
         if self.router is not None:
             route = self.router.plan(self.breakers, digest, rungs)
@@ -778,12 +777,9 @@ class AnalysisService:
                     self.faults.fire("engine.dispatch", backend=rung,
                                      machine=digest)
                 counters = {"dispatches": 0, "call": call}
-                if rung == "tick":
-                    sims = [simulate(p) for p in progs]
-                else:
-                    sims = simulate_many(progs, backend=rung,
-                                         classify=self._classify_memo,
-                                         counters=counters)
+                sims = simulate_many(progs, backend=rung,
+                                     classify=self._classify_memo,
+                                     counters=counters)
                 if self.faults is not None:
                     poisoned = []
                     for sim in sims:
@@ -982,12 +978,17 @@ class AnalysisService:
            docs/performance.md).  A 1k-point sweep is a handful of
            compiled dispatches, not 1k tick-loop runs.
 
+        A batch of one takes the same planner, so an answer does not
+        depend on how many requests share the call: under ``"auto"`` a
+        group below :data:`~repro.core.sim.AUTO_JIT_MIN_BATCH` runs the
+        numpy recurrence, which the compiled one equals bit for bit.
         The batch path and the single-request :meth:`predict` share all
         caches; for ``mode="simulate"`` they run different drivers of
         the same machine (vectorized dataflow recurrence vs reference
-        tick loop), so whichever computes a cell first fills the cache
-        for both (the drivers' agreement on the paper kernels is locked
-        by ``tests/test_simulator.py`` / ``tests/test_sweep_engine.py``).
+        tick loop, which only :meth:`predict` runs), so whichever
+        computes a cell first fills the cache for both (the drivers'
+        agreement on the paper kernels is locked by
+        ``tests/test_simulator.py`` / ``tests/test_sweep_engine.py``).
 
         Each machine group's dispatch walks the degradation ladder
         (requested rung, then every cheaper one whose circuit breaker
@@ -996,8 +997,8 @@ class AnalysisService:
         through :meth:`sweep` for crash-safe resume.
         """
         self._check_epoch()
-        if len(requests) <= 1:
-            return [self.predict(r) for r in requests]
+        if not requests:
+            return []
         call = next(_CALLS)
         with span("repro.predict_batch", call=call):
             return self._predict_batch(requests, parallel, backend,
@@ -1071,7 +1072,6 @@ class AnalysisService:
             missing = {sk: r for k, r in sim_cells.items()
                        if (sk := sim_keys[k]) not in self._sim_cache}
         if missing:
-            from .sim import AUTO_JIT_MIN_BATCH
             from .sim.batch import _resolve_backend
             chosen = backend or self.sim_backend
             # compile per request, containing injected compile faults
@@ -1090,11 +1090,8 @@ class AnalysisService:
                         floor_cells[sk] = exc.event_id
                         with self._lock:
                             self.stats.degraded_results += 1
-            # the small-batch tick-loop decision and the "auto" rung
-            # both resolve on the *total* missing count, as the single
-            # simulate_many call they replace did
-            small = (chosen == "auto"
-                     and len(compiled) < AUTO_JIT_MIN_BATCH)
+            # the "auto" rung resolves on the *total* missing count, as
+            # the single simulate_many call it replaces did
             start = chosen if chosen != "auto" else \
                 _resolve_backend("auto", len(compiled))
             groups: dict[str, list[tuple]] = {}
@@ -1103,8 +1100,8 @@ class AnalysisService:
             for digest, sks in groups.items():
                 with span("repro.dispatch", call=call):
                     self._dispatch_group(digest, sks, compiled, start,
-                                         small, _journal, call,
-                                         floor_cells, floor_route)
+                                         _journal, call, floor_cells,
+                                         floor_route)
 
         # combine analytic base + simulation per cell, then gather
         with span("repro.combine", call=call):
@@ -1148,7 +1145,7 @@ class AnalysisService:
         return out
 
     def _dispatch_group(self, digest: str, sks: list[tuple],
-                        compiled: dict, start: str, small: bool,
+                        compiled: dict, start: str,
                         _journal: dict | None, call: int,
                         floor_cells: dict, floor_route: dict) -> None:
         """Simulate one machine group (journal replay or the ladder)
@@ -1164,7 +1161,7 @@ class AnalysisService:
         else:
             sims, backend_used, degraded, counters, event_id, \
                 routed_from, probe = self._run_ladder(digest, progs, start,
-                                                      small, call)
+                                                      call)
             self._journal_record(_journal, digest, progs, sims,
                                  backend_used, degraded)
         with self._lock:

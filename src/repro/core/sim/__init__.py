@@ -9,7 +9,7 @@ the three backends compose.
 """
 from __future__ import annotations
 
-from .batch import AUTO_JIT_MIN_BATCH, JIT_SHARD, simulate_many
+from .batch import AUTO_JIT_MIN_BATCH, JIT_SHARD, simulate_many, warm_chain
 from .dag import DagNode, DagSchedule, schedule_dag
 from .pipeline import (BOTTLENECKS, DEFAULT_PARAMS, FE_MODE_NAMES,
                        FrontendSchedule, SimProgram, SimResult, SimUop,
@@ -21,5 +21,5 @@ __all__ = [
     "DagNode", "DagSchedule", "FE_MODE_NAMES", "FrontendSchedule",
     "JIT_SHARD", "SimProgram", "SimResult", "SimUop", "compile_program",
     "frontend_schedule", "schedule_dag", "simulate",
-    "simulate_kernel", "simulate_many",
+    "simulate_kernel", "simulate_many", "warm_chain",
 ]

@@ -194,6 +194,17 @@ def _bucket(n: int) -> int:
     return max(4, -(-n // 4) * 4)
 
 
+def _chain(u: int, e: int) -> tuple[int, int]:
+    """The coarse ``(U, E)`` holding ``u`` uop slots and ``e`` edges:
+    the first of (8, 16), (16, 32), (32, 64), ... that holds both.  At
+    most twice the scan steps of the tight ``_bucket`` shape (edges
+    are a vector width, not scan steps), from a handful of shapes."""
+    U = 8
+    while U < u or 2 * U < e:
+        U *= 2
+    return U, 2 * U
+
+
 def _pack(programs: list[SimProgram], ports: tuple[str, ...],
           params: PipelineParams) -> _Packed:
     B = len(programs)
@@ -393,6 +404,15 @@ def _run_numpy(pk: _Packed, n_iterations: int) -> np.ndarray:
 #: executable per (shape bucket, machine) serves all sweep sizes
 JIT_SHARD = 64
 
+#: a group of at least this many compiled lanes (a corpus study, a
+#: large sweep) pads each shard to its own tight ``_bucket`` shape: its
+#: scan steps are most of the call, and such calls recur with like
+#: shapes, so set-up can build them.  A smaller group (a service cohort:
+#: its shapes follow whatever requests share it) pads to the ``_chain``
+#: shape instead, one of the few that :func:`warm_chain` builds, so a
+#: served process builds no executable after set-up
+TIGHT_MIN_LANES = 8 * JIT_SHARD
+
 #: threads used to run shards concurrently (XLA releases the GIL)
 _POOL_WORKERS = max(1, min(4, __import__("os").cpu_count() or 1))
 _POOL = None
@@ -433,9 +453,11 @@ def _jit_compatible(programs: list[SimProgram],
 def _pack_lean(programs: list[SimProgram],
                edge_lists: list[list[tuple[int, int, float, bool]]],
                ports: tuple[str, ...], params: PipelineParams,
-               n_iterations: int) -> dict:
+               n_iterations: int,
+               shape: tuple[int, int] | None = None) -> dict:
     """Pack one shard for the compiled recurrence; ``edge_lists`` holds
-    each program's :func:`_composed_edges`.
+    each program's :func:`_composed_edges`.  The padded ``(U, E)`` is
+    ``shape``, by default the tight ``_bucket`` of the longest lane.
 
     Slot-major ``[U, B]`` layout (scan consumes leading-axis slices);
     window-gate booleans and ring index bases are precomputed here
@@ -445,8 +467,9 @@ def _pack_lean(programs: list[SimProgram],
     P = len(ports)
     T = n_iterations
     pindex = {p: i for i, p in enumerate(ports)}
-    U = _bucket(max(max((len(p.uops) for p in programs), default=0), 1))
-    E = _bucket(max(max((len(es) for es in edge_lists), default=0), 1))
+    U, E = shape or (
+        _bucket(max(max((len(p.uops) for p in programs), default=0), 1)),
+        _bucket(max(max((len(es) for es in edge_lists), default=0), 1)))
 
     active = np.zeros((U, B), bool)
     first = np.zeros((U, B), bool)
@@ -688,19 +711,24 @@ def _empty_program(model) -> SimProgram:
 def _pack_shards(programs: list[SimProgram],
                  edge_lists: list[list[tuple[int, int, float, bool]]],
                  ports: tuple[str, ...], params: PipelineParams,
-                 n_iterations: int) -> list[dict]:
+                 n_iterations: int, tight: bool = True) -> list[dict]:
     """Cut ``programs`` (sorted by the caller by uop and edge count, so
     lanes of like length share a shard and its padded ``U`` and ``E``)
     into ``JIT_SHARD``-lane shards, the last padded with empty lanes,
-    and pack each for the compiled recurrence."""
+    and pack each for the compiled recurrence, at the tight ``_bucket``
+    shape of its longest lane or, unless ``tight``, its ``_chain``
+    shape."""
     model = programs[0].model
     shards = []
     for s in range(0, len(programs), JIT_SHARD):
         chunk, edges = programs[s:s + JIT_SHARD], edge_lists[s:s + JIT_SHARD]
         pad = JIT_SHARD - len(chunk)
+        shape = None if tight else _chain(
+            max(max(len(p.uops) for p in chunk), 1),
+            max(max(len(es) for es in edges), 1))
         shards.append(_pack_lean(chunk + [_empty_program(model)] * pad,
                                  edges + [[]] * pad, ports, params,
-                                 n_iterations))
+                                 n_iterations, shape))
     return shards
 
 
@@ -714,22 +742,56 @@ def _run_jax(shards: list[dict], n_lanes: int, ports: tuple[str, ...],
     transfer, execution, fetch) is one ``repro.sim.shard`` span on the
     thread that runs it, carrying ``meta``."""
     import jax
-    import jax.numpy as jnp
 
     def run_shard(s: int) -> np.ndarray:
         pk = shards[s]
         with span("repro.sim.shard", **(meta or {}), shard=s, U=pk["U"],
                   E=pk["E"], T=n_iterations), jax.enable_x64(True):
-            fn = _compiled_run(pk["U"], pk["E"], len(ports),
-                               n_iterations, params, flavor)
-            args = [jnp.asarray(pk[k]) for k in _LEAN_ARGS]
-            return np.asarray(fn(*args))
+            return _run_shard(pk, ports, params, n_iterations, flavor)
 
     if len(shards) == 1:
         outs = [run_shard(0)]
     else:
         outs = list(_pool().map(run_shard, range(len(shards))))
     return np.concatenate(outs, axis=0)[:n_lanes]
+
+
+def _run_shard(pk: dict, ports: tuple[str, ...], params: PipelineParams,
+               n_iterations: int, flavor: str) -> np.ndarray:
+    import jax.numpy as jnp
+
+    fn = _compiled_run(pk["U"], pk["E"], len(ports), n_iterations, params,
+                       flavor)
+    return np.asarray(fn(*[jnp.asarray(pk[k]) for k in _LEAN_ARGS]))
+
+
+def warm_chain(model, n_iterations: int = 96,
+               up_to: tuple[int, int] | None = None
+               ) -> list[tuple[int, int, int]]:
+    """Build (or load from the compile cache) the compiled recurrence of
+    every ``_chain`` shape that a group smaller than
+    :data:`TIGHT_MIN_LANES` can reach on ``model``, at ``n_iterations``
+    and at the 4x horizon unconverged lanes escalate to, by running one
+    empty shard of each.  ``up_to`` is the ``(uops, composed edges)``
+    of the largest lane the caller will send, by default a full ROB of
+    uops.  Returns the ``(U, E, T)`` built; after it, such groups build
+    no executable."""
+    import jax
+
+    params = model.pipeline or DEFAULT_PARAMS
+    top, _ = _chain(*(up_to or (params.rob_size, 0)))
+    empty = [_empty_program(model)] * JIT_SHARD
+    built = []
+    for T in (n_iterations, 4 * n_iterations):
+        U = 8
+        while U <= top:
+            pk = _pack_lean(empty, [[]] * JIT_SHARD, model.ports, params,
+                            T, (U, 2 * U))
+            with jax.enable_x64(True):
+                _run_shard(pk, model.ports, params, T, "lax")
+            built.append((pk["U"], pk["E"], T))
+            U *= 2
+    return built
 
 
 # --------------------------------------------------------------------------
@@ -870,7 +932,8 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
                     warmup: int, max_period: int, backend: str,
                     classify: Callable[..., str],
                     counters: dict | None = None, *,
-                    _grown: bool = False) -> list[SimResult]:
+                    _grown: bool = False,
+                    _tight: bool | None = None) -> list[SimResult]:
     if max((len(p.uops) for p in programs), default=0) == 0:
         return [SimResult(0.0, 0, True, "empty", 0.0, {}, params)
                 for _ in programs]
@@ -897,9 +960,12 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
                 edges = {b: _composed_edges(programs[b]) for b in lanes}
                 lanes.sort(key=lambda b: (len(programs[b].uops),
                                           len(edges[b])))
+                # escalated lanes keep the padding rule of their group
+                if _tight is None:
+                    _tight = len(lanes) >= TIGHT_MIN_LANES
                 shards = _pack_shards([programs[b] for b in lanes],
                                       [edges[b] for b in lanes], ports,
-                                      params, T)
+                                      params, T, _tight)
     if not _grown:
         # one dispatch per driver invocation (the reference driver for
         # exotic lanes and the compiled one each count); the
@@ -938,7 +1004,7 @@ def _simulate_group(programs: list[SimProgram], ports: tuple[str, ...],
                 sub = _simulate_group(
                     [programs[b] for b in retry_idx], ports, params,
                     4 * n_iterations, warmup, max_period, backend,
-                    classify, counters, _grown=True)
+                    classify, counters, _grown=True, _tight=_tight)
             retry = dict(zip(retry_idx, sub))
 
     results = []

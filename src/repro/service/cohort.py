@@ -12,8 +12,9 @@ The cohort former therefore *partitions* the in-flight set by
 and dispatches each cohort as one batched engine call.  Partitioning
 (every request in exactly one cohort, no cohort mixing keys) is the
 correctness property ``tests/test_service_cohorts.py`` locks with
-hypothesis; bit-identical results vs per-request ``predict`` follow
-from the engine's own batch/single parity.
+hypothesis.  A request's answer does not depend on its cohort: every
+cohort size, one included, goes through the engine's batched planner
+and the same recurrence.
 
 The functions here are pure (no clocks, no I/O): the service hands
 them its drained queue, the tests hand them synthetic request lists.

@@ -35,7 +35,9 @@ in-process :class:`~repro.core.engine.AnalysisService` planner:
   histograms, queue-depth/batch-size distributions, per-tenant and
   per-cohort-class counters, trace events — ``export_stats()`` returns
   one JSON dict, which also feeds the analytic SLO self-model
-  (``repro.service.slo``).
+  (``repro.service.slo``).  Submit, cohort formation and each engine
+  dispatch are ``repro.service.*`` profiler spans
+  (``repro.core.spans``), beside the engine's own.
 
 See docs/serving-service.md for the worked example and
 ``benchmarks/service_bench.py`` for the load-generation harness.
@@ -50,6 +52,7 @@ from typing import Any, Sequence
 
 from repro.core.degrade import LADDER, ladder_from
 from repro.core.engine import AnalysisService
+from repro.core.spans import span
 
 from .admission import AdmissionController, AdmissionError, TenantPolicy
 from .cache import TTLCache
@@ -203,35 +206,36 @@ class PredictionService:
         tc.submitted += 1
         if self._closed or self._queue is None:
             raise ServiceClosed("service not started or stopped")
-        epoch = self.engine.registry.epoch
-        if epoch != self._registry_epoch:
-            self._registry_epoch = epoch
-            self.cache.clear()
-            self.telemetry.trace("cache_invalidated", epoch=epoch)
-        key = self._cache_key(sreq)
-        hit = self.cache.get(key, now)
-        if hit is not None:
-            tc.cache_hits += 1
-            tc.completed += 1
-            dt = loop.time() - now
-            self.telemetry.total.observe(dt)
-            return ServiceResponse(request=sreq, result=hit,
-                                   cache_hit=True, total_s=dt,
-                                   **ServiceResponse.provenance_of(hit))
-        try:
-            self.admission.admit(sreq.tenant, now)
-        except AdmissionError:
-            tc.rejected += 1
-            self.telemetry.trace("rejected", tenant=sreq.tenant,
-                                 tag=sreq.tag)
-            raise
-        tc.admitted += 1
-        timeout = sreq.timeout_s if sreq.timeout_s is not None \
-            else self.config.default_timeout_s
-        pending = _Pending(request=sreq, future=loop.create_future(),
-                           cache_key=key, t_submit=now,
-                           deadline=now + timeout)
-        self._queue.put_nowait(pending)
+        with span("repro.service.submit"):
+            epoch = self.engine.registry.epoch
+            if epoch != self._registry_epoch:
+                self._registry_epoch = epoch
+                self.cache.clear()
+                self.telemetry.trace("cache_invalidated", epoch=epoch)
+            key = self._cache_key(sreq)
+            hit = self.cache.get(key, now)
+            if hit is not None:
+                tc.cache_hits += 1
+                tc.completed += 1
+                dt = loop.time() - now
+                self.telemetry.total.observe(dt)
+                return ServiceResponse(
+                    request=sreq, result=hit, cache_hit=True, total_s=dt,
+                    **ServiceResponse.provenance_of(hit))
+            try:
+                self.admission.admit(sreq.tenant, now)
+            except AdmissionError:
+                tc.rejected += 1
+                self.telemetry.trace("rejected", tenant=sreq.tenant,
+                                     tag=sreq.tag)
+                raise
+            tc.admitted += 1
+            timeout = sreq.timeout_s if sreq.timeout_s is not None \
+                else self.config.default_timeout_s
+            pending = _Pending(request=sreq, future=loop.create_future(),
+                               cache_key=key, t_submit=now,
+                               deadline=now + timeout)
+            self._queue.put_nowait(pending)
         try:
             return await asyncio.wait_for(
                 asyncio.shield(pending.future), timeout)
@@ -271,9 +275,10 @@ class PredictionService:
                 batch.append(nxt)
             self.telemetry.queue_depth.observe(float(len(batch)))
             t_form = loop.time()
-            cohorts = form_cohorts(
-                self.engine, [p.request for p in batch],
-                max_cohort=self.config.max_cohort)
+            with span("repro.service.cohort", requests=len(batch)):
+                cohorts = form_cohorts(
+                    self.engine, [p.request for p in batch],
+                    max_cohort=self.config.max_cohort)
             self.telemetry.trace(
                 "batch_formed", requests=len(batch),
                 cohorts=len(cohorts))
@@ -299,12 +304,18 @@ class PredictionService:
                             backend: str | None = None):
         """The blocking engine call for one cohort (runs on the
         default executor).  ``backend`` overrides the cohort's batch
-        driver — the routing consult and hedged dispatch use it."""
+        driver — the routing consult and hedged dispatch use it.  An x86
+        call is one ``repro.service.dispatch`` span on the executor
+        thread, with the cohort's ``requests``; the engine's
+        ``repro.predict_batch`` span (its ``call``) nests inside it."""
         if key[0] == "x86":
             backend = backend or key[3] or self.config.backend
             reqs = [s.analysis for s in sreqs]
-            return lambda: self.engine.predict_batch(reqs,
-                                                     backend=backend)
+
+            def dispatch():
+                with span("repro.service.dispatch", requests=len(reqs)):
+                    return self.engine.predict_batch(reqs, backend=backend)
+            return dispatch
         h0 = sreqs[0].hlo
         texts = [s.hlo.text for s in sreqs]
         machine = self.engine.resolve_machine(h0.machine)
@@ -441,8 +452,7 @@ class PredictionService:
                     key, [p.request for p in live], backend=rungs[1])
         fn = self._engine_dispatch_fn(key, [p.request for p in live])
         stats = self.engine.stats
-        before = (stats.sim_group_dispatches, stats.sim_runs,
-                  stats.hlo_misses)
+        before = (stats.sim_group_dispatches, stats.hlo_misses)
         err: BaseException | None = None
         results = None
         t0 = loop.time()
@@ -500,13 +510,11 @@ class PredictionService:
         cls.cost.observe(dt)
         self.telemetry.dispatch.observe(dt)
         after = (self.engine.stats.sim_group_dispatches,
-                 self.engine.stats.sim_runs, self.engine.stats.hlo_misses)
-        d_groups, d_sims, d_hlo = (a - b for a, b in zip(after, before))
-        # one grouped simulate_many call = one compiled dispatch; the
-        # small-batch tick-loop fallback = one dispatch per simulation;
-        # each unique HLO module analyzed = one dispatch
-        self.telemetry.engine_dispatches += \
-            (d_groups if d_groups else d_sims) + d_hlo
+                 self.engine.stats.hlo_misses)
+        # one simulate_many driver invocation = one dispatch, whatever
+        # the cohort's size; each unique HLO module analyzed = one
+        self.telemetry.engine_dispatches += sum(
+            a - b for a, b in zip(after, before))
         now = loop.time()
         if err is not None:
             self.telemetry.trace("dispatch_failed",

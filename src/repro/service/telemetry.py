@@ -140,7 +140,7 @@ class Telemetry:
                                             buckets_per_decade=8)
         self.tenants: dict[str, TenantCounters] = {}
         self.cohort_classes: dict[str, CohortClassStats] = {}
-        self.engine_dispatches = 0       # compiled/tick dispatches issued
+        self.engine_dispatches = 0       # sim driver + HLO dispatches
         self.traces: deque[dict] = deque(maxlen=trace_capacity)
         self.started_at: float | None = None
         self.stopped_at: float | None = None

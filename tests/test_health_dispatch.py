@@ -221,9 +221,8 @@ def test_tick_floor_and_scheduled_probe():
 # service integration: routing provenance, budgets, hedging
 # ----------------------------------------------------------------------
 def _service_burst(tag: str) -> list[tuple[float, ServiceRequest]]:
-    # a full grid burst: large enough that each machine cohort takes
-    # the grouped dispatch path (where routing and hedging live), not
-    # the small-batch tick path
+    # a full grid burst: each machine cohort takes the grouped dispatch
+    # path, where routing and hedging live
     cells = [("skl", pk.TRIAD_SKL_O3), ("zen", pk.TRIAD_ZEN_O3),
              ("skl", pk.PI_O1), ("zen", pk.PI_O1),
              ("skl", pk.PI_O2), ("zen", pk.PI_O2),

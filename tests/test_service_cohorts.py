@@ -16,7 +16,12 @@ Satellite guarantees of the prediction service (``repro.service``):
   path under hypothesis-generated mixes, and for the full
   queue → cohort → ``simulate_many`` service path on the matched
   kernel x arch grid (the pairs pinned identical across simulator
-  drivers by tests/test_sweep_engine.py).
+  drivers by tests/test_sweep_engine.py);
+* every cohort size, one included, is answered by the compiled
+  recurrence (rung ``jit``), bit-identical to the numpy recurrence,
+  cross-request cache hits included: ``predict_batch([r])`` takes the
+  planner, and a small ``"auto"`` group runs numpy, never the tick
+  loop that only ``predict`` runs.
 """
 import pytest
 
@@ -28,8 +33,8 @@ except ModuleNotFoundError:      # optional [dev] dependency
 from repro.core import AnalysisRequest, AnalysisService, default_service
 from repro.core import paper_kernels as pk
 from repro.service import (HloRequest, PredictionService, ServiceConfig,
-                           ServiceRequest, cohort_key, form_cohorts,
-                           is_partition, replay)
+                           ServiceRequest, TenantPolicy, cohort_key,
+                           form_cohorts, is_partition, replay)
 
 SERVICE = default_service()
 
@@ -222,3 +227,144 @@ def test_oversized_cohort_split_is_stable():
     assert is_partition(cohorts, len(requests))
     flat = [i for _, m in cohorts for i in m]
     assert flat == list(range(7))
+
+
+# ---------------------------------------------------------------------
+# every cohort size answered by the device recurrence
+# ---------------------------------------------------------------------
+_FORMS = ("vaddsd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vmulsd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vaddpd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vmulpd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vfmadd132pd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vdivsd %xmm{a}, %xmm{b}, %xmm{c}",
+          "vmovaps {d}(%r13,%rax), %xmm{c}",
+          "vmovaps %xmm{a}, {d}(%r12,%rax)")
+
+
+def _body(i: int) -> str:
+    """Loop body ``i``: 1-12 drawn forms and the loop control, distinct
+    for every ``i``."""
+    import random
+    rng = random.Random(f"body/{i}")
+    lines = [rng.choice(_FORMS).format(a=rng.randrange(8),
+                                       b=rng.randrange(8),
+                                       c=rng.randrange(8),
+                                       d=16 * rng.randrange(4))
+             for _ in range(1 + i % 12)]
+    return "\n".join([f".L{i}:"] + lines + ["addq $8, %rax",
+                                            "cmpq %rbp, %rax",
+                                            f"jne .L{i}"]) + "\n"
+
+
+def _sim_request(i: int) -> AnalysisRequest:
+    return AnalysisRequest(kernel=_body(i), arch="zen", mode="simulate")
+
+
+def _answer(res) -> tuple:
+    return (res.predicted_cycles, res.bound_sim, res.binding,
+            res.sim_result)
+
+
+class _Spans:
+    """A span factory that records every span opened, with its stats
+    and the span open around it on the same thread."""
+
+    def __init__(self):
+        import threading
+        self.opened: list[tuple[str, dict, str | None]] = []
+        self._open = threading.local()
+
+    def __call__(self, name, **meta):
+        import contextlib
+        stack = self._open.__dict__.setdefault("stack", [])
+        self.opened.append((name, dict(meta),
+                            stack[-1] if stack else None))
+
+        @contextlib.contextmanager
+        def cm():
+            stack.append(name)
+            try:
+                yield None
+            finally:
+                stack.pop()
+        return cm()
+
+    def named(self, name):
+        return [stats for n, stats, _ in self.opened if n == name]
+
+    def parents(self, name):
+        return [p for n, _, p in self.opened if n == name]
+
+
+@pytest.mark.parametrize("size", [1, 2, 15, 16, 17, 63, 64, 65])
+def test_every_cohort_size_is_answered_by_the_device_recurrence(
+        monkeypatch, size):
+    """A cohort of ``size`` bodies, then the same bodies again from the
+    cross-request cache: every answer equals the numpy recurrence's
+    exactly, from the jit rung, not degraded; each submit, the cohort
+    and the one engine dispatch are ``repro.service.*`` spans."""
+    from repro.core import engine as engine_mod
+    from repro.service import service as service_mod
+
+    spans = _Spans()
+    monkeypatch.setattr(service_mod, "span", spans)
+    monkeypatch.setattr(engine_mod, "span", spans)
+    reqs = [_sim_request(1000 * size + i) for i in range(size)]
+    engine = AnalysisService(sim_backend="jit")
+    svc = PredictionService(engine, ServiceConfig(
+        batch_window_s=0.05,
+        default_policy=TenantPolicy(max_in_flight=128, burst=128.0)))
+    first = replay(svc, [(0.0, ServiceRequest(analysis=r, tenant="t"))
+                         for r in reqs])
+    want = AnalysisService(sim_backend="numpy").predict_batch(reqs)
+    assert all(r.ok for r in first), [repr(r.error) for r in first
+                                      if not r.ok]
+    assert [r.cohort_size for r in first] == [size] * size
+    assert svc.telemetry.engine_dispatches == 1
+
+    again = replay(svc, [(0.0, ServiceRequest(analysis=r, tenant="u"))
+                         for r in reqs])
+    assert all(r.ok and r.cache_hit for r in again)
+    for resp, w in zip(first + again, want + want):
+        assert _answer(resp.result) == _answer(w)
+        assert resp.backend_used == "jit" and not resp.degraded
+
+    assert len(spans.named("repro.service.submit")) == 2 * size
+    assert spans.named("repro.service.cohort") == [{"requests": size}]
+    assert spans.named("repro.service.dispatch") == [{"requests": size}]
+    # the engine's batched call, with its ``call``, nests inside the
+    # dispatch span (the numpy engine's own call follows, outside it)
+    assert spans.parents("repro.predict_batch") == [
+        "repro.service.dispatch", None]
+    assert "call" in spans.named("repro.predict_batch")[0]
+
+
+def test_batch_of_one_goes_through_the_planner():
+    """``predict_batch([r])`` is a batched call: one grouped dispatch on
+    the requested rung, never the tick loop ``predict`` runs."""
+    req = _sim_request(7)
+    engine = AnalysisService(sim_backend="jit")
+    (res,) = engine.predict_batch([req])
+    assert res.backend_used == "jit" and not res.degraded
+    assert engine.stats.sim_group_dispatches == 1
+    assert "tick" not in engine.stats.rung_attempts
+    assert engine.stats.sim_lanes == 1
+    (want,) = AnalysisService(sim_backend="numpy").predict_batch([req])
+    assert _answer(res) == _answer(want)
+    assert engine.predict_batch([]) == []
+
+
+@pytest.mark.parametrize("size,rung", [(1, "numpy"), (15, "numpy"),
+                                       (16, "jit")])
+def test_auto_group_below_the_jit_minimum_runs_numpy(size, rung):
+    """Under ``"auto"`` a group below AUTO_JIT_MIN_BATCH (16) runs the
+    numpy recurrence, not the tick loop; its answers equal the
+    compiled recurrence's."""
+    reqs = [_sim_request(50_000 + i) for i in range(size)]
+    engine = AnalysisService(sim_backend="auto")
+    got = engine.predict_batch(reqs)
+    assert {r.backend_used for r in got} == {rung}
+    assert set(engine.stats.rung_attempts) == {rung}
+    jit = AnalysisService(sim_backend="jit").predict_batch(reqs)
+    assert [_answer(r) for r in got] == [_answer(r) for r in jit]

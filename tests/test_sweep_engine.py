@@ -19,7 +19,8 @@ from repro.core.scheduler import SCHEDULERS
 from repro.core.sim import (SimProgram, SimUop, compile_program,
                             simulate, simulate_many)
 from repro.core.sim import batch
-from repro.core.sim.batch import (JIT_SHARD, _bucket, _composed_edges,
+from repro.core.sim.batch import (JIT_SHARD, TIGHT_MIN_LANES, _bucket,
+                                  _chain, _composed_edges,
                                   _jit_compatible, _steady_state)
 
 SKL = build_skylake_db()
@@ -227,11 +228,12 @@ def test_counters_count_shards_slots_and_escalation():
                         counters=counters)
     assert out == simulate_many(progs, backend="numpy", n_iterations=16)
     assert out[0].iterations == 64 and out[1].iterations == 16
-    # shapes are bucketed to multiples of 4: U = 16, then 12 for triad
+    # a group this small pads to the chain shapes: U = 16 for both
+    # shards (pi has 15 uops) and for triad's (9 uops) escalation shard
     assert counters == {
         "dispatches": 1, "sim_lanes": 70, "sim_host_lanes": 0,
         "sim_device_calls": 2 + 1, "sim_escalated_lanes": 1,
-        "sim_slot_capacity": 2 * 16 * 64 * 16 + 12 * 64 * 64,
+        "sim_slot_capacity": 2 * 16 * 64 * 16 + 16 * 64 * 64,
         "sim_slot_steps": (9 + 69 * 15) * 16 + 9 * 64}
 
 
@@ -280,9 +282,12 @@ def _heavy_tailed_batch():
 
 
 def _capacity(progs, T):
-    """``sim_slot_capacity`` of one pass over ``progs`` in this order."""
+    """``sim_slot_capacity`` of one pass over ``progs`` in this order,
+    in a group small enough for the chain shapes."""
     return T * JIT_SHARD * sum(
-        _bucket(max(len(p.uops) for p in progs[s:s + JIT_SHARD]))
+        _chain(max(len(p.uops) for p in progs[s:s + JIT_SHARD]),
+               max(len(_composed_edges(p))
+                   for p in progs[s:s + JIT_SHARD]))[0]
         for s in range(0, len(progs), JIT_SHARD))
 
 
@@ -314,8 +319,8 @@ def test_sorted_shards_scan_fewer_padded_slots():
 
 
 def test_one_shard_batch_keeps_its_shape(monkeypatch):
-    """At most 64 lanes make one shard, packed to the (U, E) of the
-    whole batch, as in arrival order."""
+    """At most 64 lanes make one shard, packed to the chain shape of
+    the whole batch, as in arrival order."""
     progs = _heavy_tailed_batch()[0][:64]
     params = SKL.model.pipeline
     lanes = [p for p in progs if _jit_compatible([p], params)]
@@ -329,9 +334,89 @@ def test_one_shard_batch_keeps_its_shape(monkeypatch):
     monkeypatch.setattr(batch, "_pack_shards", spy)
     out = simulate_many(progs, backend="jit")
     assert all(r.iterations == 96 for r in out)
-    assert shapes == [(
-        _bucket(max(len(p.uops) for p in lanes)),
-        _bucket(max(len(_composed_edges(p)) for p in lanes)))]
+    assert shapes == [_chain(
+        max(len(p.uops) for p in lanes),
+        max(len(_composed_edges(p)) for p in lanes))]
+
+
+def _spy_shapes(monkeypatch):
+    """Record the ``(U, E, T)`` of every shard the jit driver runs."""
+    run_shard, shapes = batch._run_shard, []
+
+    def spy(pk, ports, params, T, flavor):
+        shapes.append((pk["U"], pk["E"], T))
+        return run_shard(pk, ports, params, T, flavor)
+
+    monkeypatch.setattr(batch, "_run_shard", spy)
+    return shapes
+
+
+def test_large_group_keeps_tight_shapes_small_group_takes_the_chain(
+        monkeypatch):
+    """A group of TIGHT_MIN_LANES lanes or more (a corpus study) pads
+    each shard to its own ``_bucket``, its escalation shard too; the
+    same lanes in a smaller group pad to the chain shapes.  Either way
+    the answers equal numpy's."""
+    params = SKL.model.pipeline
+    lanes = [p for p in _heavy_tailed_batch()[0]
+             if _jit_compatible([p], params)]
+    big = (lanes * 3)[:TIGHT_MIN_LANES]
+    shapes = _spy_shapes(monkeypatch)
+    out = simulate_many(big, backend="jit", n_iterations=48)
+    assert out == simulate_many(big, backend="numpy", n_iterations=48)
+    lanes = sorted(big, key=lambda p: (len(p.uops),
+                                       len(_composed_edges(p))))
+    want = [(_bucket(max(len(p.uops) for p in ch)),
+             _bucket(max(len(_composed_edges(p)) for p in ch)), 48)
+            for ch in (lanes[s:s + JIT_SHARD]
+                       for s in range(0, len(lanes), JIT_SHARD))]
+    assert shapes[:len(want)] == want
+    grown = [p for p, r in zip(big, out) if r.iterations == 4 * 48]
+    assert grown and shapes[len(want):] == [(
+        _bucket(max(len(p.uops) for p in grown)),
+        _bucket(max(len(_composed_edges(p)) for p in grown)), 4 * 48)]
+
+    shapes.clear()
+    small = big[:TIGHT_MIN_LANES - 1]
+    assert simulate_many(small, backend="jit", n_iterations=48) == \
+        out[:TIGHT_MIN_LANES - 1]
+    chain = {_chain(u, e) for u in range(1, 300) for e in range(600)}
+    assert shapes and all((U, E) in chain for U, E, _ in shapes)
+
+
+def test_chain_shapes_are_few_and_hold_their_lanes():
+    assert [_chain(u, 0) for u in (1, 8, 9, 16, 17, 100, 224)] == [
+        (8, 16), (8, 16), (16, 32), (16, 32), (32, 64), (128, 256),
+        (256, 512)]
+    # edges are a vector width: a lane with many edges steps up a level
+    assert _chain(5, 17) == (16, 32) and _chain(5, 16) == (8, 16)
+    for u in range(1, 257):
+        for e in range(0, 513, 7):
+            U, E = _chain(u, e)
+            assert U >= max(u, _bucket(u)) and E >= e
+            assert U < 2 * max(u, 8) or E < 2 * e
+
+
+def test_after_warm_chain_a_small_group_builds_nothing(monkeypatch):
+    """``warm_chain`` builds every chain shape up to the largest lane
+    at both horizons; a small group of lanes no larger then reaches no
+    executable that is not built yet."""
+    from repro.core.sim import warm_chain
+
+    progs = [p for p in _heavy_tailed_batch()[0]
+             if _jit_compatible([p], SKL.model.pipeline)][:100]
+    top = (max(len(p.uops) for p in progs),
+           max(len(_composed_edges(p)) for p in progs))
+    built = warm_chain(SKL.model, 24, up_to=top)
+    U = _chain(*top)[0]
+    levels = [8 << k for k in range(U.bit_length() - 3)]
+    assert built == [(u, 2 * u, T) for T in (24, 96) for u in levels]
+    shapes = _spy_shapes(monkeypatch)
+    for size in (1, 2, 15, 17, 64, 65, 100):
+        assert simulate_many(progs[:size], backend="jit",
+                             n_iterations=24) == \
+            simulate_many(progs[:size], backend="numpy", n_iterations=24)
+    assert shapes and set(shapes) <= set(built)
 
 
 def test_counters_of_exotic_lanes():
@@ -355,7 +440,7 @@ def test_planner_surfaces_sim_counters_in_stats():
     progs = [compile_program(extract_kernel(src), SKL)
              for src in PAPER_KERNELS.values()]
     uops = [len(p.uops) for p in progs]
-    U = -(-max(uops) // 4) * 4
+    U = _chain(max(uops), max(len(_composed_edges(p)) for p in progs))[0]
     s = svc.stats
     assert s.sim_group_dispatches == 1
     assert (s.sim_lanes, s.sim_device_calls, s.sim_host_lanes,

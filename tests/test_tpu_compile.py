@@ -23,9 +23,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import compile_program, extract_kernel, get_model
 from repro.core import paper_kernels as pk
-from repro.core.sim.batch import (JIT_SHARD, _LEAN_ARGS, _compiled_run,
-                                  _composed_edges, _empty_program,
-                                  _pack_lean)
+from repro.core.sim.batch import (JIT_SHARD, _LEAN_ARGS, _chain,
+                                  _compiled_run, _composed_edges,
+                                  _empty_program, _pack_lean)
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -86,6 +86,27 @@ def test_lax_recurrence_compiles_in_float64(one_chip, arch, kernels, T):
         run = _compiled_run(shard["U"], shard["E"], len(model.ports), T,
                             model.pipeline, "lax")
         compiled = run.lower(*args).compile()
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("T", [96, 384])
+def test_largest_chain_shape_compiles_in_float64(one_chip, T):
+    """The largest shape ``warm_chain`` builds for a served Zen
+    process (the chain level that holds a full ROB of uops) compiles
+    and fits the chip."""
+    model = get_model("zen")
+    U, E = _chain(model.pipeline.rob_size, 0)
+    empty = [_empty_program(model)] * JIT_SHARD
+    shard = _pack_lean(empty, [[]] * JIT_SHARD, model.ports,
+                       model.pipeline, T, (U, E))
+    assert (shard["U"], shard["E"]) == (256, 512)
+    with jax.enable_x64(True):
+        args = _shapes(one_chip, *((np.shape(shard[k]),
+                                    np.asarray(shard[k]).dtype)
+                                   for k in _LEAN_ARGS))
+        compiled = _compiled_run(U, E, len(model.ports), T,
+                                 model.pipeline, "lax").lower(
+                                     *args).compile()
     _fits_one_chip(compiled)
 
 
