@@ -129,6 +129,8 @@ class ServiceStats:
     hlo_misses: int = 0
     sim_runs: int = 0        # cycle-level simulations actually executed
     #                          (cache hits are counted in result_hits)
+    parse_hits: int = 0      # memoized kernel.extract_kernel of a
+    parse_misses: int = 0    # source-text kernel
     edge_hits: int = 0       # memoized latency.dependency_edges
     edge_misses: int = 0
     program_hits: int = 0    # memoized sim.compile_program
@@ -175,9 +177,9 @@ class ServiceStats:
 
     def hit_rate(self, kind: str) -> float:
         """Hit rate in [0, 1] for one counter pair (``"result"``,
-        ``"lookup"``, ``"lp"``, ``"hlo"``, ``"edge"``, ``"program"``,
-        ``"classify"``, ``"machine"`` or ``"traffic"``); 0.0 when
-        never exercised."""
+        ``"lookup"``, ``"lp"``, ``"hlo"``, ``"parse"``, ``"edge"``,
+        ``"program"``, ``"classify"``, ``"machine"`` or ``"traffic"``);
+        0.0 when never exercised."""
         hits = getattr(self, f"{kind}_hits")
         misses = getattr(self, f"{kind}_misses")
         total = hits + misses
@@ -209,6 +211,7 @@ class AnalysisService:
         self._results: dict[tuple, AnalysisResult] = {}
         self._sim_cache: dict[tuple, object] = {}   # SimResult by kernel
         self._hlo_cache: dict[tuple, object] = {}
+        self._parse_cache: dict[tuple, tuple] = {}  # parsed source text
         self._edge_cache: dict[tuple, tuple] = {}   # dependency edges
         self._program_cache: dict[tuple, object] = {}   # SimProgram
         self._classify_cache: dict[tuple, str] = {}
@@ -497,9 +500,26 @@ class AnalysisService:
     # prediction entry points
     # ------------------------------------------------------------------
     def _kernel_of(self, req: AnalysisRequest) -> tuple[Instruction, ...]:
-        if isinstance(req.kernel, str):
-            return tuple(extract_kernel(req.kernel, syntax=req.syntax))
-        return tuple(req.kernel)
+        """The parsed kernel of one request.
+
+        Source text is parsed once per (text, syntax) and memoized: the
+        analytic pass, the dependency edges, the program compile and the
+        ECM traffic pass all draw the same tuple of frozen instructions
+        (``stats.parse_hits`` / ``parse_misses``).  The parse does not
+        depend on the machine, so the memo survives re-registration."""
+        if not isinstance(req.kernel, str):
+            return tuple(req.kernel)
+        key = self._kernel_id(req)
+        with self._lock:
+            hit = self._parse_cache.get(key)
+            if hit is not None:
+                self.stats.parse_hits += 1
+                return hit
+            self.stats.parse_misses += 1
+        out = tuple(extract_kernel(req.kernel, syntax=req.syntax))
+        with self._lock:
+            self._parse_cache[key] = out
+        return out
 
     @staticmethod
     def _kernel_id(req: AnalysisRequest) -> tuple:
@@ -1403,6 +1423,7 @@ class AnalysisService:
             self._sim_cache.clear()
             self._sim_provenance.clear()
             self._hlo_cache.clear()
+            self._parse_cache.clear()
             self._edge_cache.clear()
             self._program_cache.clear()
             self._classify_cache.clear()

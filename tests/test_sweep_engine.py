@@ -471,6 +471,71 @@ def test_service_dependency_edges_memoized():
     assert svc.dependency_edges(pk.PI_O1, "skylake") is e1
 
 
+def _count_parses(monkeypatch):
+    """Wrap the engine's extract_kernel with a call counter."""
+    import repro.core.engine as engine
+    calls = []
+    parse = engine.extract_kernel
+
+    def counted(*args, **kwargs):
+        calls.append(args[0])
+        return parse(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "extract_kernel", counted)
+    return calls
+
+
+@pytest.mark.parametrize("arch,other", [("skl", "zen"), ("zen", "skl")])
+def test_predict_batch_parses_each_body_once(monkeypatch, arch, other):
+    texts = list(dict.fromkeys(PAPER_KERNELS.values()))
+    calls = _count_parses(monkeypatch)
+    svc = AnalysisService()
+    reqs = [AnalysisRequest(kernel=t, arch=arch, mode="simulate")
+            for t in texts]
+    svc.predict_batch(reqs)
+    n = len(texts)
+    # the analytic pass, the dependency edges and the program compile
+    # draw one parse
+    assert len(calls) == n and sorted(calls) == sorted(texts)
+    assert svc.stats.parse_misses == n
+    assert svc.stats.parse_hits >= 2 * n
+    svc.predict_batch(reqs)
+    # the parse is machine-independent: another arch reuses it too
+    hits = svc.stats.parse_hits
+    svc.predict_batch([AnalysisRequest(kernel=t, arch=other,
+                                       mode="simulate") for t in texts])
+    assert len(calls) == n and svc.stats.parse_misses == n
+    assert svc.stats.parse_hits >= hits + 2 * n
+
+
+def test_parse_memo_keys_on_syntax(monkeypatch):
+    text = "add rax, rbx\nimul rax, rcx\n"
+    calls = _count_parses(monkeypatch)
+    svc = AnalysisService()
+    att = svc._kernel_of(AnalysisRequest(kernel=text, syntax="att"))
+    intel = svc._kernel_of(AnalysisRequest(kernel=text, syntax="intel"))
+    assert len(calls) == 2 and svc.stats.parse_misses == 2
+    assert att != intel
+    assert att == tuple(extract_kernel(text, syntax="att"))
+    assert intel == tuple(extract_kernel(text, syntax="intel"))
+    assert svc._kernel_of(
+        AnalysisRequest(kernel=text, syntax="intel")) is intel
+    assert svc.stats.parse_hits == 1
+
+
+def test_cache_clear_drops_parse_memo(monkeypatch):
+    calls = _count_parses(monkeypatch)
+    svc = AnalysisService()
+    svc.dependency_edges(pk.PI_O1, "skl")
+    svc.dependency_edges(pk.PI_O2, "skl")
+    assert svc.stats.parse_misses == 2 and len(calls) == 2
+    svc.cache_clear()
+    assert svc._parse_cache == {}
+    assert (svc.stats.parse_hits, svc.stats.parse_misses) == (0, 0)
+    svc.dependency_edges(pk.PI_O1, "skl")
+    assert svc.stats.parse_misses == 1 and len(calls) == 3
+
+
 def test_classify_memo_counts():
     svc = AnalysisService()
     assert svc._classify_memo(9.0, 2.0, 4.75) == "dependencies"
